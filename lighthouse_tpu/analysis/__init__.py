@@ -1,6 +1,6 @@
 """Repo-wide static invariant analyzer.
 
-One entrypoint (``tools/pyrun tools/static_audit.py``) runs six lint
+One entrypoint (``JAX_PLATFORMS=cpu python tools/static_audit.py``) runs six lint
 families over the package and emits a JSON report, failing on any
 unwaivered violation:
 

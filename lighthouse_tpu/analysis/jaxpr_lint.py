@@ -56,7 +56,7 @@ _SCALARIZERS = {"float", "int", "bool"}
 def iter_jaxprs(obj):
     """Yield every Jaxpr reachable from a params value (ClosedJaxpr,
     Jaxpr, or containers thereof)."""
-    import jax.core as jcore
+    import jax.extend.core as jcore
 
     if isinstance(obj, jcore.ClosedJaxpr):
         yield obj.jaxpr

@@ -239,7 +239,7 @@ def _eqn_src(eqn) -> tuple:
     """(source file hint, line) for an eqn, best effort."""
     try:
         from jax._src import source_info_util
-        frame = source_info_util.user_frame(eqn.source_info)
+        frame = source_info_util.user_frame(eqn.source_info.traceback)
         if frame is not None:
             return frame.file_name, int(frame.start_line)
     except Exception:
@@ -1841,7 +1841,7 @@ def run(root: str, cfg, only: tuple = ()) -> list:
                 symbol="missing",
                 message=(
                     f"checked-in range report unreadable ({exc}); "
-                    f"regenerate with tools/pyrun tools/static_audit.py "
+                    f"regenerate with JAX_PLATFORMS=cpu python tools/static_audit.py "
                     f"--write-range-report"
                 ),
             ))
@@ -1855,7 +1855,7 @@ def run(root: str, cfg, only: tuple = ()) -> list:
                 message=(
                     "checked-in range report drifted from the kernels: "
                     + "; ".join(diffs[:6])
-                    + " — regenerate with tools/pyrun "
+                    + " — regenerate with JAX_PLATFORMS=cpu python "
                       "tools/static_audit.py --write-range-report"
                 ),
             ))

@@ -243,7 +243,9 @@ def trace_mesh(axes):
     devices are touched, so any width is analyzable anywhere."""
     from jax.sharding import AbstractMesh
 
-    return AbstractMesh(tuple((str(k), int(v)) for k, v in axes))
+    axes = list(axes)
+    return AbstractMesh(tuple(int(v) for _, v in axes),
+                        tuple(str(k) for k, _ in axes))
 
 
 # ---------------------------------------------------------------------------
@@ -976,11 +978,10 @@ def _find_shard_maps(jaxpr, out=None):
     return out
 
 
-def _names_dict(entry):
-    """Normalize one shard_map in_names/out_names entry to a dict."""
-    if isinstance(entry, dict):
-        return entry
-    return dict(getattr(entry, "items", lambda: {})()) or {}
+def _names_dict(spec):
+    """One shard_map in_specs/out_specs PartitionSpec as ``{dim: axes}``
+    over its sharded dims — empty when the operand is replicated."""
+    return {d: axes for d, axes in enumerate(spec) if axes is not None}
 
 
 def _check_mesh_program(prog: SpmdProgram, closed, declared,
@@ -997,9 +998,8 @@ def _check_mesh_program(prog: SpmdProgram, closed, declared,
         axis_sizes = {str(k): int(v)
                       for k, v in dict(getattr(mesh, "shape", {})).items()}
         inner = eqn.params["jaxpr"]
-        in_names = [_names_dict(e) for e in eqn.params.get("in_names", ())]
-        out_names = [_names_dict(e)
-                     for e in eqn.params.get("out_names", ())]
+        in_names = [_names_dict(e) for e in eqn.params["in_specs"]]
+        out_names = [_names_dict(e) for e in eqn.params["out_specs"]]
         interp = _Interp(prog, findings, axis_sizes, declared)
         in_avs = []
         for i, var in enumerate(inner.invars):
@@ -1416,7 +1416,7 @@ def build_live_programs() -> list:
                 args = _flat_stub_args(b_pad)
                 local = P.staged_local(_stub_verify, axis="batch")
                 specs = P.program_in_specs(args, deferred_pk=False)
-                fn = M.compat_shard_map(local, amesh, in_specs=specs,
+                fn = M.shard_map(local, amesh, in_specs=specs,
                                         out_specs=P._ps())
                 return fn, args
             return build
@@ -1451,7 +1451,7 @@ def build_live_programs() -> list:
                     pk_wrap=lambda x, y: (x, y),
                 )
                 specs = P.program_in_specs(rest, deferred_pk=True)
-                fn = M.compat_shard_map(local, amesh, in_specs=specs,
+                fn = M.shard_map(local, amesh, in_specs=specs,
                                         out_specs=P._ps())
                 return fn, args
             return build
@@ -1517,7 +1517,7 @@ def build_live_programs() -> list:
                         jnp.reshape(x, ()), lambda a, b: a & b, "batch",
                     )
 
-                fn = M.compat_shard_map(
+                fn = M.shard_map(
                     local, amesh, in_specs=P._ps("batch"),
                     out_specs=P._ps(),
                 )
@@ -1542,7 +1542,7 @@ def build_live_programs() -> list:
             args = _flat_stub_args(b_pad)
             local = P.staged_local(_stub_verify, axis="batch")
             specs = P.program_in_specs(args, deferred_pk=False)
-            fn = M.compat_shard_map(local, amesh, in_specs=specs,
+            fn = M.shard_map(local, amesh, in_specs=specs,
                                     out_specs=P._ps())
             return fn, args
         return build

@@ -32,7 +32,7 @@ log = get_logger("processor")
 
 
 class WorkKind(Enum):
-    """Work taxonomy (the `Work` enum, lib.rs:562 — the kinds the
+    """Work kinds (the `Work` enum, lib.rs:562 — the kinds the
     implemented layers emit; extended as layers land)."""
 
     CHAIN_SEGMENT = auto()

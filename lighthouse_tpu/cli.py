@@ -14,6 +14,8 @@ import time
 
 
 def build_parser() -> argparse.ArgumentParser:
+    from .serve.stack import BLS_BACKENDS
+
     p = argparse.ArgumentParser(
         prog="lighthouse-tpu",
         description="TPU-native Ethereum consensus framework",
@@ -25,6 +27,10 @@ def build_parser() -> argparse.ArgumentParser:
     bn.add_argument("--validators", type=int, default=64)
     bn.add_argument("--http-port", type=int, default=5052)
     bn.add_argument("--datadir", default=None, help="slabdb path (memory if unset)")
+    bn.add_argument("--bls-backend", choices=BLS_BACKENDS, default="python",
+                    help="BLS engine that verifies: jax runs the batch "
+                         "kernel on JAX's default device (the TPU where "
+                         "one is attached)")
     bn.add_argument("--slots", type=int, default=0,
                     help="exit after N slots (0 = run until interrupted)")
     bn.add_argument("--auto-propose", action="store_true",
@@ -211,6 +217,14 @@ def run_bn(args) -> int:
     import logging
 
     log = get_logger("bn")
+    from .serve.stack import select_bls_backend
+
+    device = select_bls_backend(args.bls_backend)
+    if args.bls_backend == "jax":
+        from .crypto.bls.jax_backend.backend import enable_compile_cache
+
+        device["compile_cache"] = enable_compile_cache()
+    log_with(log, logging.INFO, "BLS backend selected", **device)
     if getattr(args, "scenario", None):
         from .scenario import parse_scenario_arg
         from .scenario.engine import ScenarioEngine
@@ -268,18 +282,6 @@ def run_bn(args) -> int:
         from .store import HotColdDB, SlabStore
 
         os.makedirs(args.datadir, exist_ok=True)
-        # JAX persistent compilation cache keyed under the node data dir:
-        # a restarted node reloads its compiled BLS programs instead of
-        # re-paying the XLA compile (ROADMAP item 4).  Best-effort.
-        try:
-            from .crypto.bls.jax_backend.backend import enable_compile_cache
-
-            if enable_compile_cache(os.path.join(args.datadir, "jax_cache")):
-                log_with(log, logging.INFO, "JAX compile cache enabled",
-                         path=os.path.join(args.datadir, "jax_cache"))
-        except Exception as exc:  # noqa: BLE001 — cache is optional
-            log_with(log, logging.WARNING, "JAX compile cache unavailable",
-                     error=str(exc))
         store = HotColdDB(
             store=SlabStore(os.path.join(args.datadir, "beacon.slab")),
             types_family=types_for(spec.preset),

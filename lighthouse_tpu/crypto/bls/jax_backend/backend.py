@@ -19,7 +19,9 @@ from __future__ import annotations
 
 import hashlib
 import json
+import os
 import secrets
+import threading
 import time
 from dataclasses import dataclass, field
 from typing import Any
@@ -38,34 +40,65 @@ from . import points as P
 from . import tower as T
 
 
-def enable_compile_cache(cache_dir: str) -> bool:
-    """Point JAX's persistent compilation cache at ``cache_dir`` so node
-    restarts reuse compiled BLS programs instead of re-paying minutes of
-    XLA time (ROADMAP item 4).  Best-effort: returns False (never raises)
-    when jax or the cache config is unavailable."""
-    import os
+#: the checkout this package runs from: the compile cache's fixed home
+CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))))))
 
+
+def _cpu_machine_tag() -> str:
+    """Short fingerprint of this host's CPU feature set.  XLA:CPU
+    executables bake in the compile machine's vector flags, and loading
+    one on a host with other flags can crash mid-run, so CPU cache
+    entries are kept per feature set."""
+    try:
+        with open("/proc/cpuinfo") as f:
+            for line in f:
+                # x86 "flags", aarch64 "Features"
+                if line.startswith(("flags", "Features")):
+                    return hashlib.sha256(line.encode()).hexdigest()[:12]
+    except OSError:
+        pass
+    import platform
+
+    return hashlib.sha256(
+        (platform.machine() + platform.processor() + platform.node()).encode()
+    ).hexdigest()[:12]
+
+
+def enable_compile_cache() -> str | None:
+    """Turn on JAX's persistent compilation cache and return its
+    directory, so a restart reuses compiled BLS programs instead of
+    paying minutes of compile again.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, places the cache: JAX reads
+    it itself and no directory is set here.  Otherwise the cache is the
+    fixed ``<checkout>/.jax_cache`` (a path that moves never hits), with
+    a subdirectory per CPU feature set when the backend is the CPU.
+    Never raises: a dead cache only costs compile time, so the failure
+    is counted on /metrics and logged, and None is returned."""
     try:
         import jax
 
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
+        cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+        if not cache_dir:
+            cache_dir = os.path.join(CHECKOUT, ".jax_cache")
+            if jax.default_backend() == "cpu":
+                cache_dir = os.path.join(cache_dir,
+                                         f"cpu-{_cpu_machine_tag()}")
+            os.makedirs(cache_dir, exist_ok=True)
+            jax.config.update("jax_compilation_cache_dir", cache_dir)
         # the BLS programs are exactly the long-compile case the cache
         # exists for; cache even small/fast entries so tests exercise it
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-        return True
-    except Exception as exc:  # noqa: BLE001 — cache is an optimization,
-        # not a dep — but a dead cache re-pays full compile time on every
-        # boot, so the failure must be loud: a counter on /metrics plus a
-        # structured log line, not a swallowed warning.
+        return cache_dir
+    except Exception as exc:  # noqa: BLE001 — see docstring
         COMPILE_CACHE_ERRORS.inc()
         from ....utils import get_logger, log_with
 
         log_with(get_logger("bls.jax"), 30,
-                 "persistent compile cache unavailable",
-                 cache_dir=cache_dir, error=str(exc))
-        return False
+                 "persistent compile cache unavailable", error=str(exc))
+        return None
 
 
 def program_fingerprint(kernel: str, **attrs) -> str:
@@ -362,19 +395,25 @@ def _neg_gen_const():
     return P.g1_encode([ng])
 
 
+_PIN_LOCK = threading.Lock()
+
+
 def _pin_mxu(fn, mxu: bool):
     """Trace ``fn`` under a pinned kernel arm.  The routed plane is read
     by the program body at TRACE time (``fp.mxu_active`` inside the
     Montgomery products), so a per-shape plan that differs from the
     process-wide gate must hold the toggle around the traced call; the
     override is restored exactly, and compiled executions skip the
-    Python body entirely — the pin costs nothing after the first call."""
+    Python body entirely — the pin costs nothing after the first call.
+    The toggle is process-wide, so concurrent traces (``VerifyStack.warm``)
+    take turns; their compiles still overlap."""
     def armed(*args):
-        prev = F.set_mxu(mxu)
-        try:
-            return fn(*args)
-        finally:
-            F.set_mxu(prev)
+        with _PIN_LOCK:
+            prev = F.set_mxu(mxu)
+            try:
+                return fn(*args)
+            finally:
+                F.set_mxu(prev)
 
     armed.__name__ = fn.__name__
     return armed
@@ -385,10 +424,17 @@ class JaxBackend:
 
     name = "jax"
 
-    def __init__(self, min_batch: int = 8, device_h2c: bool | None = None):
+    def __init__(self, min_batch: int = 8, device_h2c: bool | None = None,
+                 batch_sizes=None):
         self._kernels = {}
         self._aot_store = None
-        self.min_batch = min_batch
+        # The padded batch sizes, one compiled program each: a batch of
+        # n sets runs at the smallest size >= n, past the largest at its
+        # doublings.  A serving process pins these to the sizes it warms
+        # (serve/stack.py), so bisection halves and canary batches reuse
+        # warm programs instead of compiling new shapes mid-traffic; the
+        # default is every power of two from ``min_batch``.
+        self.batch_sizes = tuple(sorted(batch_sizes or (min_batch,)))
         # device_h2c: map messages to G2 ON DEVICE (host only hashes).
         # Measured on the v5e at B=512 (PERF.md): host marshal 120 -> 5,008
         # sets/s/core while the kernel pays +70% (2,655 -> 1,565 sets/s) for
@@ -495,27 +541,32 @@ class JaxBackend:
         tiled along the batch axis (every kernel operand is batch-last).
         Goes through the normal ``_kernel`` path, so spans, metrics and
         AOT capture fire exactly as for organic traffic."""
-        from ..api import SecretKey, SignatureSet
-
         import jax
 
-        if B < self.min_batch or B & (B - 1):
+        if self._padded_size(B) != B:
             return False
+        self._kernel(B)(*jax.device_put(self.warm_batch(B).args))
+        return True
+
+    def warm_batch(self, B: int) -> MarshalledBatch:
+        """A valid marshalled batch of exactly ``B`` columns: one
+        synthetic set, marshalled once and tiled along the batch axis
+        (every kernel operand is batch-last)."""
+        import jax
+
+        from ..api import SecretKey, SignatureSet
+
         sk = SecretKey(2)
         msg = b"lighthouse-tpu warm-compile probe"
         s = SignatureSet(sk.sign(msg), [sk.public_key()], msg)
         mb = self.marshal_sets([s], weights=[1])
-        if mb.invalid:
-            return False
-        reps = B // mb.B
         args = jax.tree_util.tree_map(
             lambda a: np.tile(
-                np.asarray(a), (1,) * (np.asarray(a).ndim - 1) + (reps,)
+                np.asarray(a), (1,) * (np.asarray(a).ndim - 1) + (B // mb.B,)
             ),
             mb.args,
         )
-        self._kernel(B)(*jax.device_put(args))
-        return True
+        return MarshalledBatch(B, B, self.device_h2c, args)
 
     # -- single/aggregate verification reuses the set machinery ------------
 
@@ -699,9 +750,18 @@ class JaxBackend:
     def verify_marshalled(self, mb: MarshalledBatch) -> bool:
         return False if mb.invalid else self.resolve(self.dispatch(mb))
 
+    @property
+    def min_batch(self) -> int:
+        return self.batch_sizes[0]
+
     def _padded_size(self, n: int) -> int:
-        """Next power-of-two batch size >= n (bounded recompiles per size)."""
-        B = self.min_batch
+        """The compiled batch size a batch of ``n`` sets runs at: the
+        smallest of ``batch_sizes`` >= n, else the largest doubled until
+        it holds n (bounded recompiles per size)."""
+        for B in self.batch_sizes:
+            if B >= n:
+                return B
+        B = self.batch_sizes[-1]
         while B < n:
             B *= 2
         return B

@@ -472,8 +472,8 @@ _MILLER_MODE: bool | None = None
 
 def miller_enabled() -> bool:
     """Fused Miller-step kernels (pallas_miller.py): DEFAULT ON since the
-    r5 on-chip A/B (3,061 vs 2,607 sets/s at B=512; 6,221 at B=8192 —
-    TPU_SESSION_r05.jsonl).  LIGHTHOUSE_TPU_MILLER=0 reverts to the
+    r5 on-chip A/B (3,061 vs 2,607 sets/s at B=512; 6,221 at B=8192,
+    BENCH_HISTORY.jsonl).  LIGHTHOUSE_TPU_MILLER=0 reverts to the
     stacked per-op pallas calls."""
     global _MILLER_MODE
     if _MILLER_MODE is None:

@@ -22,7 +22,7 @@ from ....parallel.mesh import (
     allgather_tree,
     and_reduce,
     batch_spec,
-    compat_shard_map,
+    shard_map,
     ring_reduce,
 )
 from . import fp as F
@@ -31,10 +31,6 @@ from . import points as P
 from . import tower as T
 from .backend import _neg_gen_const, _tree_reduce_g2
 
-# The version shim lives in parallel/mesh.py now so the rule-driven
-# sharded program (parallel/partition.py) and these kernels share one
-# guard; the old private name stays importable for external callers.
-_shard_map = compat_shard_map
 
 
 def _trailing_extent(tree) -> int:
@@ -121,7 +117,7 @@ def make_verify_sharded(mesh: Mesh, axis: str = "batch"):
     # (analysis/spmd_lint.py), whose own replication check proves the
     # scan-with-replicated-carry pattern device-identical through
     # exactly the typing gap check_vma trips over here.
-    sharded = _shard_map(
+    sharded = shard_map(
         local_part,
         mesh=mesh,
         in_specs=(in_spec, in_spec, in_spec, in_spec),
@@ -176,7 +172,7 @@ def make_pair_sharded_aggregate_verify(mesh: Mesh, axis: str = "batch"):
         ok_pair = PR.final_exp_is_one(total)
         return jnp.reshape(ok_pair & ok_sub, ())
 
-    sharded = _shard_map(
+    sharded = shard_map(
         local_part,
         mesh=mesh,
         in_specs=(pair_spec, pair_spec, PS(), batch_spec(1, axis=axis)),

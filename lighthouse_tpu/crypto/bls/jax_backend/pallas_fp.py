@@ -26,6 +26,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from . import fp as F
 
@@ -46,16 +47,30 @@ _PP_COLS = np.asarray(F.int_to_limbs(F.PPRIME_INT)).reshape(26, 1)
 MASK = np.uint32((1 << 15) - 1)
 
 
+# The helpers below run at TRACE time once per unrolled limb step — a
+# fused Miller step kernel calls them ~10^5 times — so they stage plain
+# lax primitives: the jnp wrappers (dtype promotion, index rewriting)
+# cost ~5x more host time per op for the same jaxpr.
+_ZERO = np.uint32(0)
+_SHIFT = np.uint32(15)
+
+
+def _rows(x, a: int, b: int):
+    """Static row slice x[a:b] of a (rows, T) value."""
+    return lax.slice_in_dim(x, a, b, axis=0)
+
+
 def _compress1(cols):
     """One carry pass (fp.compress1, in-kernel): lo < 2^15 plus the
     column below's high bits.  On the wide-product accumulator
     (<= 1,677,799 < 2^20.7) one pass lands <= 32818 and a second
     <= 32768 <= QMAX (intervals range_lint-verified).  Shift expressed
     as pad+slice (Mosaic has no scatter-add)."""
-    lo = cols & MASK
-    hi = cols >> 15
-    shifted = jnp.pad(hi[:-1], ((1, 0), (0, 0)))
-    return lo + shifted
+    lo = lax.bitwise_and(cols, MASK)
+    hi = lax.shift_right_logical(cols, _SHIFT)
+    shifted = lax.pad(_rows(hi, 0, hi.shape[0] - 1), _ZERO,
+                      ((1, 0, 0), (0, 0, 0)))
+    return lax.add(lo, shifted)
 
 
 def _acc_add(acc, rows, offset: int):
@@ -64,7 +79,13 @@ def _acc_add(acc, rows, offset: int):
     pad/concatenate + add; it has neither scatter-add nor value-level
     dynamic_slice)."""
     tail = acc.shape[0] - offset - rows.shape[0]
-    return acc + jnp.pad(rows, ((offset, tail), (0, 0)))
+    return lax.add(acc, lax.pad(rows, _ZERO, ((offset, tail, 0), (0, 0, 0))))
+
+
+def _limb_times(a, i: int, b):
+    """a_i * b: limb row i of ``a`` broadcast over the rows of ``b``."""
+    return lax.mul(lax.broadcast_in_dim(_rows(a, i, i + 1), b.shape, (0, 1)),
+                   b)
 
 
 def _wide_product(a, b):
@@ -74,13 +95,11 @@ def _wide_product(a, b):
     in-bounds slice-adds — the clipped-slice variant lowers to a
     scatter Pallas cannot stage."""
     T = a.shape[1]
-    acc = jnp.zeros((52, T), dtype=jnp.uint32)
+    acc = lax.full((52, T), 0, jnp.uint32)
     for i in range(26):
-        p = a[i][None, :] * b  # (26, T) 32-bit products
-        plo = p & MASK
-        phi = p >> 15
-        acc = _acc_add(acc, plo, i)
-        acc = _acc_add(acc, phi, i + 1)
+        p = _limb_times(a, i, b)  # (26, T) 32-bit products
+        acc = _acc_add(acc, lax.bitwise_and(p, MASK), i)
+        acc = _acc_add(acc, lax.shift_right_logical(p, _SHIFT), i + 1)
         # column sums peak at 1,677,799 < 2^20.7 (range_lint): no overflow
     return _compress1(_compress1(acc))
 
@@ -90,10 +109,9 @@ def _wide_square(a):
     diagonal single — 351 limb products instead of 676.  Shapes stay
     static per unrolled i (tail slices), which Mosaic handles."""
     T = a.shape[1]
-    acc = jnp.zeros((52, T), dtype=jnp.uint32)
+    acc = lax.full((52, T), 0, jnp.uint32)
     for i in range(26):
-        tail = a[i:]  # (26-i, T)
-        p = a[i][None, :] * tail  # a_i * a_j, j >= i
+        p = _limb_times(a, i, _rows(a, i, 26))  # a_i * a_j, j >= i
         # double the cross terms (j > i); diagonal stays single.
         # products <= QMAX^2 ~ 2^30.01, doubled < 2^31.02 — the
         # repo-wide uint32 high-water mark (range_lint max_acc): the
@@ -102,11 +120,10 @@ def _wide_square(a):
         # which real Mosaic lowering rejects ("vector types must have
         # positive constant sizes") even though interpret mode allows it
         if p.shape[0] > 1:
-            p = jnp.concatenate([p[:1], p[1:] + p[1:]], axis=0)
-        plo = p & MASK
-        phi = p >> 15
-        acc = _acc_add(acc, plo, 2 * i)
-        acc = _acc_add(acc, phi, 2 * i + 1)
+            cross = _rows(p, 1, p.shape[0])
+            p = lax.concatenate([_rows(p, 0, 1), lax.add(cross, cross)], 0)
+        acc = _acc_add(acc, lax.bitwise_and(p, MASK), 2 * i)
+        acc = _acc_add(acc, lax.shift_right_logical(p, _SHIFT), 2 * i + 1)
     return _compress1(_compress1(acc))
 
 
@@ -115,17 +132,17 @@ def _mont_reduce(t, pl_, pp):
     (the low half of the full product — columns < 26 coincide with the
     low product's), u = m·P, then one full carry normalization whose low
     26 limbs vanish (divisible by R)."""
-    m = _wide_product(t[:26], pp)[:26]
+    m = _rows(_wide_product(_rows(t, 0, 26), pp), 0, 26)
     u = _wide_product(m, pl_)
-    s = t + u  # <= 2^16 per column: both double-compressed <= 32768 (range_lint)
-    carry = jnp.zeros((t.shape[1],), dtype=jnp.uint32)
+    s = lax.add(t, u)  # <= 2^16 per column: both double-compressed <= 32768 (range_lint)
+    carry = lax.full((1, t.shape[1]), 0, jnp.uint32)
     out_rows = []
     for k in range(52):
-        tcol = s[k] + carry
-        carry = tcol >> 15
+        tcol = lax.add(_rows(s, k, k + 1), carry)
+        carry = lax.shift_right_logical(tcol, _SHIFT)
         if k >= 26:
-            out_rows.append(tcol & MASK)
-    return jnp.stack(out_rows, axis=0)
+            out_rows.append(lax.bitwise_and(tcol, MASK))
+    return lax.concatenate(out_rows, 0)
 
 
 def _mont_sqr_core(a, pl_, pp):

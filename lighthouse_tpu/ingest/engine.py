@@ -98,6 +98,14 @@ class IngestEngine:
     def close(self) -> None:
         self.pool.close()
 
+    def sync_registry(self) -> int:
+        """Pull new validators from the beacon pubkey cache into the
+        limb cache's registry tier (no-op without one); returns how
+        many were added."""
+        if self._pubkey_cache is None:
+            return 0
+        return self.cache.sync_registry(self._pubkey_cache)
+
     def _use_device_gather(self) -> bool:
         if self._device_gather is None:
             import jax
@@ -159,8 +167,7 @@ class IngestEngine:
         if not sets:
             return MarshalledBatch(0, 0, backend.device_h2c, invalid=True)
         n = len(sets)
-        if self._pubkey_cache is not None:
-            self.cache.sync_registry(self._pubkey_cache)
+        self.sync_registry()
         t0 = time.perf_counter()
         with TRACER.span("ingest.marshal", sets=n):
             # Validation mirrors the scalar loop's early-outs: any

@@ -15,8 +15,8 @@ at multi-host registry scale, KZG blob batches):
 * ``allgather_tree(tree, axis)`` — gather a pytree's trailing axis
   across the mesh (the tiny ICI combine),
 * ``and_reduce(ok, axis)`` — the global conjunction,
-* ``compat_shard_map`` / ``compat_jit_sharded`` — the jax-version
-  compatibility seams every mesh program in the repo routes through.
+* ``shard_map`` — the one shard_map call every mesh program in the
+  repo routes through.
 """
 
 from __future__ import annotations
@@ -29,53 +29,15 @@ from jax.sharding import Mesh, PartitionSpec as PS
 BATCH_AXIS = "batch"
 
 
-# ---------------------------------------------------------------------------
-# jax-version compatibility seams
-# ---------------------------------------------------------------------------
-
-
-def compat_shard_map(f, mesh, in_specs, out_specs):
-    """shard_map across jax versions: top-level ``jax.shard_map`` with
-    ``check_vma`` where available, else ``jax.experimental.shard_map``
-    with its older ``check_rep`` spelling.  Both flags are the same
-    check disabled for the same reason: every Horner/Montgomery scan in
-    fp.py initializes its carry from a replicated constant while the
-    loop body mixes in batch-varying limbs, which the vma/rep checker
+def shard_map(f, mesh, in_specs, out_specs):
+    """``jax.shard_map`` with ``check_vma`` off: every Horner/Montgomery
+    scan in fp.py initializes its carry from a replicated constant while
+    the loop body mixes in batch-varying limbs, which the vma checker
     rejects (see the scan-carry note in multichip.make_verify_sharded —
     correctness is pinned by the shard-vs-single byte-equality tests
     instead)."""
-    try:
-        from jax import shard_map as sm
-
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as sm
-
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
-
-
-def compat_jit_sharded(f, in_shardings=None, out_shardings=None, **jit_kw):
-    """``jax.jit`` with explicit shardings across the supported jax
-    range — the pjit path the rule-driven sharded program compiles
-    through (partition.py).  Modern jax spells pjit as
-    ``jax.jit(in_shardings=...)``; older releases only accept the
-    sharding kwargs on ``jax.experimental.pjit.pjit``.  The guard is a
-    real call probe, not a version parse: a jax that *has* the kwargs
-    but rejects our values should raise loudly, so only TypeError on
-    the jit() call itself (unknown kwarg) falls through."""
-    kw = dict(jit_kw)
-    if in_shardings is not None:
-        kw["in_shardings"] = in_shardings
-    if out_shardings is not None:
-        kw["out_shardings"] = out_shardings
-    try:
-        return jax.jit(f, **kw)
-    except TypeError:
-        from jax.experimental.pjit import pjit
-
-        return pjit(f, **kw)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def make_mesh(n_devices: int | None = None, axis: str = BATCH_AXIS) -> Mesh:
@@ -161,7 +123,7 @@ def dp_shard_map(local_fn, mesh: Mesh, axis: str = BATCH_AXIS,
 
     def wrapped(*args):
         in_specs = jax.tree.map(spec_for, args)
-        return compat_shard_map(
+        return shard_map(
             local_fn,
             mesh=mesh,
             in_specs=in_specs,

@@ -28,9 +28,8 @@ The pieces, bottom-up:
   placing shard k+1 overlaps compute of shard k) and the matching
   host-gather.
 * **the program** — :class:`ShardedVerifyProgram` wraps the backend's
-  *local* verify kernel in ``compat_shard_map`` with the rule-derived
-  ``in_specs`` and jits it through ``compat_jit_sharded`` (the pjit
-  path) with the matching ``in_shardings``.  Each device verifies its
+  *local* verify kernel in ``shard_map`` with the rule-derived
+  ``in_specs`` and jits it with the matching ``in_shardings``.  Each device verifies its
   batch columns; ``all_gather`` of the per-shard conjunction yields the
   replicated verdict vector — one bool per shard crosses ICI, nothing
   else returns to host.
@@ -62,7 +61,7 @@ from typing import Any, Callable, Iterable, Iterator
 
 import numpy as np
 
-from .mesh import BATCH_AXIS, compat_jit_sharded, compat_shard_map
+from .mesh import BATCH_AXIS, shard_map
 
 AXIS = BATCH_AXIS
 
@@ -470,6 +469,7 @@ class ShardedVerifyProgram:
         return args[3:] if deferred_pk else args
 
     def _build(self, args, deferred_pk: bool):
+        import jax
         from jax.sharding import NamedSharding
 
         in_specs = program_in_specs(
@@ -480,15 +480,15 @@ class ShardedVerifyProgram:
             self.local_verify_fn, axis=self.axis, deferred_pk=deferred_pk,
             pk_wrap=self.pk_wrap,
         )
-        sharded = compat_shard_map(
+        sharded = shard_map(
             local, self.mesh, in_specs=in_specs, out_specs=_ps()
         )
         shardings = _map_specs(
             lambda s: NamedSharding(self.mesh, s), in_specs
         )
-        # the pjit path: explicit in_shardings pin the rule table's
-        # placement so pre-sharded operands are never silently resharded
-        return compat_jit_sharded(sharded, in_shardings=shardings)
+        # explicit in_shardings pin the rule table's placement so
+        # pre-sharded operands are never silently resharded
+        return jax.jit(sharded, in_shardings=shardings)
 
 
 def program_in_specs(semantic_args, *, deferred_pk: bool,
@@ -512,7 +512,7 @@ def staged_local(fn, *, axis: str = AXIS, deferred_pk: bool = False,
                  pk_wrap: Callable | None = None):
     """The per-device body of the staged program: registry gather (in
     deferred-pk mode), the local kernel, then the verdict all_gather.
-    This is the exact callable ``_build`` wraps in ``compat_shard_map``
+    This is the exact callable ``_build`` wraps in ``shard_map``
     — the spmd lint traces it rather than a paraphrase."""
     import jax
     import jax.numpy as jnp

@@ -290,6 +290,8 @@ class PodVerifier:
             else 4.0 * shard_timeout
         )
         self._sharded_programs: dict = {}
+        # concurrent warm-up jobs share one program per mesh
+        self._programs_lock = threading.Lock()
         self.shard_timeout = shard_timeout
         self.max_shard_retries = max(0, max_shard_retries)
         self.backoff_base = backoff_base
@@ -341,6 +343,38 @@ class PodVerifier:
         except Exception as exc:  # noqa: BLE001 — opportunistic wiring
             log.warning("pod wiring unavailable: %s", exc)
             return None
+
+    def warm_compile(self, B: int) -> None:
+        """Compile and run the sharded program over the full mesh for a
+        padded batch of ``B`` columns, with no deadline: a cold program
+        compiles for minutes, far past ``sharded_timeout``, and a first
+        dispatch that timed out would strand the batch on the per-device
+        coordinator.  Raises if the program cannot run or rejects the
+        valid warm batch."""
+        program = self._full_mesh_program()
+        if not all(program.verdict_vector(self.backend.warm_batch(B).args)):
+            raise RuntimeError(
+                f"sharded program rejected the valid warm batch at B={B}")
+
+    def warm_registry(self, B: int) -> None:
+        """:meth:`warm_compile` for the deferred-pubkey program that
+        all-registry batches run; a no-op while the registry is empty
+        (no batch can take that program).  Its verdict on the synthetic
+        slots is meaningless, so only compiling and running is checked."""
+        import numpy as np
+
+        if self.registry_provider is None or self.sharded_marshal is None:
+            return
+        program = self._full_mesh_program()
+        registry = self.registry_provider(program.mesh)
+        if registry[0].shape[1]:
+            program.verdict_vector_registry(
+                registry, np.zeros(B, dtype=np.int32),
+                self.backend.warm_batch(B).args[1:])
+
+    def _full_mesh_program(self):
+        width = mesh_width(len(self.devices()))
+        return self._sharded_program(tuple(range(width)))
 
     def devices(self) -> list:
         if self._devices is None:
@@ -521,23 +555,24 @@ class PodVerifier:
         # verdict replication, pad absorption, gather bounds — cover
         # these dispatches at their characteristic width/batch shapes
         # (see analysis/spmd_lint.build_live_programs)
-        prog = self._sharded_programs.get(key)
-        if prog is None:
-            import numpy as np
-            from jax.sharding import Mesh
+        with self._programs_lock:
+            prog = self._sharded_programs.get(key)
+            if prog is None:
+                import numpy as np
+                from jax.sharding import Mesh
 
-            from .mesh import BATCH_AXIS
-            from .partition import ShardedVerifyProgram
+                from .mesh import BATCH_AXIS
+                from .partition import ShardedVerifyProgram
 
-            devs = [self.devices()[i] for i in key]
-            mesh = Mesh(np.array(devs), (BATCH_AXIS,))
-            prog = ShardedVerifyProgram(
-                mesh,
-                self.backend.local_verify_fn(),
-                pk_wrap=getattr(self.backend, "registry_pk_wrap", None),
-            )
-            self._sharded_programs[key] = prog
-        return prog
+                devs = [self.devices()[i] for i in key]
+                mesh = Mesh(np.array(devs), (BATCH_AXIS,))
+                prog = ShardedVerifyProgram(
+                    mesh,
+                    self.backend.local_verify_fn(),
+                    pk_wrap=getattr(self.backend, "registry_pk_wrap", None),
+                )
+                self._sharded_programs[key] = prog
+            return prog
 
     def _run_sharded(self, program, mb):
         self.injector.fire("pod.dispatch")
@@ -584,6 +619,7 @@ class PodVerifier:
             worker.start()
             worker.join(self.sharded_timeout)
         if "verdicts" not in result:
+            M.POD_SHARDED_FALLBACKS.inc()
             err = result.get("error")
             log.warning(
                 "pod sharded program %s; falling back to per-device "
@@ -596,6 +632,7 @@ class PodVerifier:
                 for v in result["verdicts"]
             ]
         except Exception as exc:  # noqa: BLE001 — chaos gather domain
+            M.POD_SHARDED_FALLBACKS.inc()
             log.warning("pod sharded gather failed: %s", exc)
             return None
         self.resilient.breaker.record_success()

@@ -30,6 +30,7 @@ from __future__ import annotations
 
 import json
 import threading
+from collections import OrderedDict
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
 from ..utils.logging import get_logger
@@ -60,11 +61,41 @@ def _unhex(s: str) -> bytes:
     return bytes.fromhex(s[2:] if s.startswith("0x") else s)
 
 
-def decode_sets(raw) -> list:
+class PubkeyDecodeCache:
+    """Bounded LRU of decoded, subgroup-checked public keys keyed by
+    their wire bytes.  Decoding one costs ~9 ms of Python, almost all of
+    it the subgroup check, and tenants send the same validator keys in
+    batch after batch: a 440-member committee set would otherwise spend
+    ~4 s in decode alone.  Bytes that fail to decode are never cached."""
+
+    def __init__(self, capacity: int = 1 << 16):
+        self.capacity = int(capacity)
+        self._lock = threading.Lock()
+        self._keys: OrderedDict = OrderedDict()
+
+    def decode(self, data: bytes):
+        from ..crypto.bls.api import PublicKey
+
+        with self._lock:
+            pk = self._keys.get(data)
+            if pk is not None:
+                self._keys.move_to_end(data)
+                return pk
+        pk = PublicKey.from_bytes(data)
+        with self._lock:
+            self._keys[data] = pk
+            while len(self._keys) > self.capacity:
+                self._keys.popitem(last=False)
+        return pk
+
+
+def decode_sets(raw, pubkeys: PubkeyDecodeCache | None = None) -> list:
     """Wire set dicts -> validated ``SignatureSet`` objects.  Raises
     ``ValueError`` on any shape or point-decode problem (the transport
     maps that to 400)."""
     from ..crypto.bls.api import PublicKey, Signature, SignatureSet
+
+    decode_pk = PublicKey.from_bytes if pubkeys is None else pubkeys.decode
 
     if not isinstance(raw, list) or not raw:
         raise ValueError("sets must be a non-empty list")
@@ -74,8 +105,7 @@ def decode_sets(raw) -> list:
             raise ValueError(f"set {i}: expected an object")
         try:
             sig = Signature.from_bytes(_unhex(entry["signature"]))
-            pks = [PublicKey.from_bytes(_unhex(p))
-                   for p in entry["pubkeys"]]
+            pks = [decode_pk(_unhex(p)) for p in entry["pubkeys"]]
             msg = _unhex(entry["message"])
         except (KeyError, TypeError, ValueError) as exc:
             raise ValueError(f"set {i}: {exc}") from exc
@@ -97,10 +127,12 @@ class ServeApiServer:
         self._httpd: ThreadingHTTPServer | None = None
         self._thread: threading.Thread | None = None
         self.port: int = 0
+        self.pubkeys = PubkeyDecodeCache()
 
     def start(self) -> "ServeApiServer":
         global _LAST
         service = self.service
+        pubkeys = self.pubkeys
 
         class Handler(BaseHTTPRequestHandler):
             def log_message(self, fmt, *args):  # quiet access log
@@ -133,7 +165,7 @@ class ServeApiServer:
                         self._error(400, "expected a JSON object")
                         return
                     try:
-                        sets = decode_sets(body.get("sets"))
+                        sets = decode_sets(body.get("sets"), pubkeys)
                     except ValueError as exc:
                         self._error(400, str(exc))
                         return

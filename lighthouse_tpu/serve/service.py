@@ -39,7 +39,8 @@ from .batcher import DeadlineAwareBatcher
 log = get_logger("serve.service")
 
 #: compiled device batch sizes the batcher fills toward when the caller
-#: does not pin its own (matches the backend's min_batch ladder scale)
+#: does not pin its own; ``standalone`` pins the device backend's padded
+#: sizes to the same list, so every dispatch runs a warm program
 DEFAULT_COMPILED_SIZES = (64, 256, 1024)
 
 
@@ -129,6 +130,10 @@ class VerifyService:
         self.batcher = DeadlineAwareBatcher(
             compiled_sizes, flush_margin=flush_margin, now=now,
         )
+        self.compiled_sizes = tuple(self.batcher.sizes)
+        #: the VerifyStack behind ``verifier`` when built by
+        #: :meth:`standalone`, else None
+        self.stack = None
         self._lock = threading.Lock()
         self._requests: dict[str, ServeRequest] = {}
         self._done_order: list[str] = []
@@ -142,16 +147,23 @@ class VerifyService:
         self.deadline_misses: dict[str, int] = {}
 
     @classmethod
-    def standalone(cls, *, pubkey_cache=None, injector=None, **kw):
+    def standalone(cls, *, pubkey_cache=None, injector=None,
+                   compiled_sizes=DEFAULT_COMPILED_SIZES, **kw):
         """Build the full ladder via the shared construction path and a
-        service over it — no ``BeaconNode`` anywhere."""
+        service over it — no ``BeaconNode`` anywhere.  The stack stays
+        reachable as ``service.stack`` (``stack.warm(service.compiled_sizes)``
+        compiles the device programs before traffic)."""
         from .stack import build_verify_stack
 
         stack = build_verify_stack(
             pubkey_cache=pubkey_cache, injector=injector,
+            batch_sizes=compiled_sizes,
         )
-        return cls(stack.verifier, breaker=stack.breaker,
-                   injector=stack.injector, **kw)
+        service = cls(stack.verifier, breaker=stack.breaker,
+                      injector=stack.injector,
+                      compiled_sizes=compiled_sizes, **kw)
+        service.stack = stack
+        return service
 
     def rotate_epoch(self, epoch: int) -> None:
         """Epoch hook: rotate the verdict-integrity canary corpus when

@@ -24,7 +24,30 @@ sync manager, the service's epoch hook) reaches the same instances.
 
 from __future__ import annotations
 
+import threading
+import time
 from dataclasses import dataclass
+
+#: the ``--bls-backend`` choices of ``bn`` and ``tools/serve.py``
+BLS_BACKENDS = ("python", "jax", "fake")
+
+
+def select_bls_backend(name: str) -> dict:
+    """Make ``name`` the process's active BLS backend and say where it
+    verifies: ``{"backend", "platform", "kind", "count"}``, the device
+    fields naming JAX's devices for ``jax`` and the host for the others
+    (the boot log line of ``bn`` and ``tools/serve.py``)."""
+    from ..crypto.bls import api as _bls_api
+
+    _bls_api.set_backend(name)
+    if name != "jax":
+        return {"backend": name, "platform": "host", "kind": name,
+                "count": 1}
+    import jax
+
+    devices = jax.devices()
+    return {"backend": name, "platform": devices[0].platform,
+            "kind": devices[0].device_kind, "count": len(devices)}
 
 
 @dataclass
@@ -44,6 +67,57 @@ class VerifyStack:
     # the IntegrityGuard wrapping ``verifier`` when the verdict-integrity
     # layer is on (integrity/guard.py), else None
     integrity: object | None = None
+    # the device backend the ingest rung marshals for, else None
+    backend: object | None = None
+
+    def warm(self, sizes) -> dict:
+        """Compile every program this stack dispatches at each padded
+        batch size in ``sizes`` before traffic arrives: the backend's
+        single-device kernel (the ladder's rung) and, behind a pod, the
+        sharded programs.  The programs compile concurrently — tracing
+        holds the interpreter lock, the compiler does not — and the
+        result maps each size to the seconds until its last program was
+        ready.  A no-op ({}) without a device backend."""
+        if self.backend is None:
+            return {}
+        # the registry decides whether the pod's deferred-pubkey program
+        # is in play, so it must be current before the pod warms
+        self.ingest.sync_registry()
+        jobs = []
+        # largest first: it compiles longest, so its compile overlaps
+        # the smaller sizes' traces
+        for B in sorted(sizes, reverse=True):
+            jobs.append((int(B), self._warm_device))
+            if self.pod is not None:
+                jobs += [(int(B), self.pod.warm_compile),
+                         (int(B), self.pod.warm_registry)]
+        secs: dict = {}
+        errors: list = []
+        lock = threading.Lock()
+        t0 = time.perf_counter()
+
+        def run(B: int, job) -> None:
+            try:
+                job(B)
+            except Exception as exc:  # noqa: BLE001 — re-raised below
+                errors.append(exc)
+            with lock:
+                secs[B] = max(secs.get(B, 0.0), time.perf_counter() - t0)
+
+        threads = [threading.Thread(target=run, args=job, name=f"warm-{job[0]}")
+                   for job in jobs]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+        if errors:
+            raise errors[0]
+        return dict(sorted(secs.items()))
+
+    def _warm_device(self, B: int) -> None:
+        if not self.backend.warm_compile(B):
+            raise ValueError(f"{B} is not a padded batch size of "
+                             f"{self.backend.batch_sizes}")
 
 
 def _make_ingest_device_verify(ingest):
@@ -71,7 +145,8 @@ def _make_ingest_device_verify(ingest):
 def build_verify_stack(pubkey_cache=None, injector=None,
                        breaker=None, aot_store=None,
                        prewarm=False, integrity="auto",
-                       canary_k=None, audit_fraction=0.0) -> VerifyStack:
+                       canary_k=None, audit_fraction=0.0,
+                       batch_sizes=None) -> VerifyStack:
     """Assemble the full verification ladder against the active backend.
 
     Parameters
@@ -112,6 +187,12 @@ def build_verify_stack(pubkey_cache=None, injector=None,
     audit_fraction:
         Fraction of accepted batches re-verified by the cross-arm audit
         sampler (0.0 disables sampling; the canary layer is unaffected).
+    batch_sizes:
+        The padded batch sizes the device backend compiles (a serving
+        front end passes its batcher's compiled sizes, so every batch it
+        dispatches — bisection halves and canaries included — runs on a
+        program :meth:`VerifyStack.warm` compiled).  None keeps the
+        backend's own sizes.
     """
     from ..beacon.processor import CircuitBreaker, ResilientVerifier
     from ..crypto.bls import api as _bls_api
@@ -130,6 +211,8 @@ def build_verify_stack(pubkey_cache=None, injector=None,
             from ..crypto.bls.jax_backend import aot as _aot
 
             prewarm_report = _aot.prewarm(_active, aot_store)
+    if batch_sizes is not None and hasattr(_active, "batch_sizes"):
+        _active.batch_sizes = tuple(sorted(batch_sizes))
     if hasattr(_active, "marshal_sets") and hasattr(_active, "dispatch"):
         from ..ingest import IngestEngine
 
@@ -181,4 +264,5 @@ def build_verify_stack(pubkey_cache=None, injector=None,
         breaker=breaker, verifier=verifier, resilient=resilient,
         ingest=ingest, pod=pod, injector=injector,
         prewarm_report=prewarm_report, integrity=guard,
+        backend=_active if ingest is not None else None,
     )

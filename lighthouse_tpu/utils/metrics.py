@@ -347,6 +347,11 @@ POD_FALLBACKS = Counter(
     "Batches the pod handed to the single-device ResilientVerifier ladder "
     "(mesh exhausted, breaker open, or shard verdict False)",
 )
+POD_SHARDED_FALLBACKS = Counter(
+    "pod_sharded_fallbacks_total",
+    "Batches whose sharded program failed or timed out and went to the "
+    "per-device coordinator instead",
+)
 
 # ---------------------------------------------------------------------------
 # Multi-peer sync + peer scoring (beacon/sync.py SyncManager,

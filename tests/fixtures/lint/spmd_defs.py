@@ -13,7 +13,7 @@ import importlib.util
 import os
 
 from lighthouse_tpu.analysis.spmd_lint import SpmdProgram, trace_mesh
-from lighthouse_tpu.parallel.mesh import compat_shard_map
+from lighthouse_tpu.parallel.mesh import shard_map
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _REL = "tests/fixtures/lint"
@@ -40,7 +40,7 @@ def _ps(*parts):
 def _mesh_prog(local, axes, in_specs, mk_args):
     def build():
         amesh = trace_mesh(axes)
-        fn = compat_shard_map(
+        fn = shard_map(
             local, amesh, in_specs=in_specs, out_specs=_ps()
         )
         return fn, mk_args()

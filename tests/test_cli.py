@@ -3,6 +3,8 @@ auto-proposing bn run (the L0 smoke)."""
 
 import json
 
+import pytest
+
 from lighthouse_tpu.cli import main
 
 
@@ -217,3 +219,19 @@ def test_bn_metrics_port_serves_scrape_endpoints(capsys):
 
     t.join(timeout=60)
     assert not t.is_alive() and rc["bn"] == 0
+
+
+@pytest.mark.parametrize("name", ["fake", "python"])
+def test_bn_bls_backend_selects_the_named_engine(name, capsys):
+    from lighthouse_tpu.crypto.bls import api
+
+    prev = api.get_backend()
+    try:
+        rc = main([
+            "--spec", "minimal", "bn", "--validators", "8",
+            "--http-port", "0", "--slots", "1", "--bls-backend", name,
+        ])
+        assert rc == 0
+        assert api.get_backend().name == name
+    finally:
+        api._ACTIVE[0] = prev

@@ -11,11 +11,12 @@ import numpy as np
 import pytest
 
 import __graft_entry__ as graft
+from lighthouse_tpu.crypto.bls.jax_backend.backend import enable_compile_cache
 
 
 @pytest.mark.slow
 def test_dryrun_multichip_8():
-    graft._enable_compile_cache(__import__("jax"))
+    enable_compile_cache()
     graft.dryrun_multichip(8)  # asserts valid batch -> True, poisoned -> False
 
 
@@ -27,7 +28,7 @@ def test_sharded_matches_single_chip():
     from lighthouse_tpu.crypto.bls.jax_backend.backend import _verify_kernel
     from lighthouse_tpu.crypto.bls.jax_backend.multichip import make_verify_sharded
 
-    graft._enable_compile_cache(jax)
+    enable_compile_cache()
     args = graft._example_batch(8)
     mesh = Mesh(np.array(jax.devices()[:8]), ("batch",))
     sharded = make_verify_sharded(mesh)
@@ -51,7 +52,7 @@ def test_pair_sharded_aggregate_verify_ring():
         make_pair_sharded_aggregate_verify,
     )
 
-    graft._enable_compile_cache(jax)
+    enable_compile_cache()
     n_pairs = 8
     sks = [SecretKey(7000 + i) for i in range(n_pairs)]
     msgs = [bytes([i]) * 32 for i in range(n_pairs)]
@@ -101,7 +102,7 @@ def test_sharded_accepts_non_divisible_batch():
     from lighthouse_tpu.crypto.bls.jax_backend import points as P
     from lighthouse_tpu.crypto.bls.jax_backend.multichip import make_verify_sharded
 
-    graft._enable_compile_cache(jax)
+    enable_compile_cache()
     args = graft._example_batch(6)
     mesh = Mesh(np.array(jax.devices()[:8]), ("batch",))
     sharded = make_verify_sharded(mesh)
@@ -126,7 +127,7 @@ def test_pair_sharded_non_divisible_pair_count():
         make_pair_sharded_aggregate_verify,
     )
 
-    graft._enable_compile_cache(jax)
+    enable_compile_cache()
     n_pairs = 6
     sks = [SecretKey(8000 + i) for i in range(n_pairs)]
     msgs = [bytes([40 + i]) * 32 for i in range(n_pairs)]

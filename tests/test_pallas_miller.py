@@ -40,10 +40,12 @@ pytestmark = [pytest.mark.compile]
 
 # the fused kernels are the largest single compiles in the repo (~160
 # unrolled Montgomery multiplies per kernel): persistent cache makes the
-# SECOND run of any variant instant (bench/graft do the same)
-import __graft_entry__ as _graft
+# SECOND run of any variant instant
+from lighthouse_tpu.crypto.bls.jax_backend.backend import (  # noqa: E402
+    enable_compile_cache,
+)
 
-_graft._enable_compile_cache(jax)
+enable_compile_cache()
 
 
 def rand_pairs(n):
@@ -76,8 +78,7 @@ _MILLER_OPTIN = pytest.mark.skipif(
     os.environ.get("LIGHTHOUSE_TPU_MILLER_PROOFS", "") != "1",
     reason="each isolated fused-miller proof is a ~45-55 min fresh-process "
     "interpret compile (the XLA:CPU persistent cache does not cover "
-    "them); run standalone with LIGHTHOUSE_TPU_MILLER_PROOFS=1 — green "
-    "runs are recorded in MILLER_RECHECK.log",
+    "them); run standalone with LIGHTHOUSE_TPU_MILLER_PROOFS=1",
 )
 
 

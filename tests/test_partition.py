@@ -17,7 +17,7 @@ Runs on the conftest's virtual 8-device CPU mesh.  Families:
   memory stays O(chunk) over an epoch-sized stream (tracemalloc-pinned);
 * registry mirror — ``registry_device_sharded`` shrinks per-device
   bytes by the mesh width;
-* compat shims — ``compat_shard_map`` / ``compat_jit_sharded`` drive a
+* the shard_map seam — ``shard_map`` + ``jax.jit(in_shardings=)`` drive a
   mesh program end-to-end on this jax version.
 
 The real-kernel mesh byte-identity runs (random / all-invalid /
@@ -35,8 +35,7 @@ from lighthouse_tpu.beacon.processor import CircuitBreaker, ResilientVerifier
 from lighthouse_tpu.parallel import partition as P
 from lighthouse_tpu.parallel.mesh import (
     BATCH_AXIS,
-    compat_jit_sharded,
-    compat_shard_map,
+    shard_map,
     make_mesh,
 )
 from lighthouse_tpu.parallel.pod import PodVerifier
@@ -604,11 +603,11 @@ class TestShardedRegistryMirror:
 
 
 # ---------------------------------------------------------------------------
-# Version-compat shims (parallel/mesh)
+# The shard_map seam (parallel/mesh)
 # ---------------------------------------------------------------------------
 
 
-class TestCompatShims:
+class TestShardMapSeam:
     def test_shard_map_and_jit_sharded_run_a_mesh_program(self, mesh8):
         import jax
         import jax.numpy as jnp
@@ -617,43 +616,14 @@ class TestCompatShims:
         def local(x):
             return jax.lax.psum(jnp.sum(x), BATCH_AXIS)
 
-        fn = compat_shard_map(
+        fn = shard_map(
             local, mesh8, in_specs=PS(BATCH_AXIS), out_specs=PS()
         )
-        jfn = compat_jit_sharded(
+        jfn = jax.jit(
             fn, in_shardings=NamedSharding(mesh8, PS(BATCH_AXIS))
         )
         x = jnp.arange(16.0)
         assert float(jfn(x)) == float(x.sum())
-
-    def test_jit_sharded_falls_back_to_pjit_on_typeerror(self, monkeypatch):
-        import jax
-
-        calls = []
-
-        def fake_jit(f, **kw):
-            calls.append(kw)
-            raise TypeError("no in_shardings here")
-
-        monkeypatch.setattr(jax, "jit", fake_jit)
-        sentinel = object()
-
-        def fake_pjit(f, **kw):
-            calls.append(("pjit", tuple(sorted(kw))))
-            return sentinel
-
-        import jax.experimental.pjit as pjit_mod
-
-        monkeypatch.setattr(pjit_mod, "pjit", fake_pjit)
-        out = compat_jit_sharded(lambda x: x, in_shardings="s")
-        assert out is sentinel
-        assert calls[0]["in_shardings"] == "s"
-        assert calls[1][0] == "pjit"
-
-    def test_multichip_private_alias_still_importable(self):
-        from lighthouse_tpu.crypto.bls.jax_backend import multichip
-
-        assert multichip._shard_map is compat_shard_map
 
 
 # ---------------------------------------------------------------------------

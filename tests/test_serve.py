@@ -463,3 +463,84 @@ def test_standalone_service_builds_without_a_beacon_node():
     assert svc.admission.breaker is svc.breaker
     assert hasattr(svc._verifier, "verify_batch")
     svc.stop()
+
+
+def test_standalone_pins_backend_batch_sizes_and_keeps_its_stack(
+        monkeypatch):
+    """The service's compiled sizes become the device backend's padded
+    sizes, so bisection halves and canaries run warm programs."""
+    from lighthouse_tpu.crypto.bls import api
+    from lighthouse_tpu.crypto.bls.jax_backend.backend import JaxBackend
+
+    backend = JaxBackend(min_batch=8)
+    monkeypatch.setitem(api._BACKENDS, "jax", backend)
+    prev = api.get_backend()
+    api.set_backend("jax")
+    try:
+        svc = VerifyService.standalone(compiled_sizes=(256, 64, 1024))
+        assert svc.compiled_sizes == (64, 256, 1024)
+        assert backend.batch_sizes == (64, 256, 1024)
+        assert svc.stack.backend is backend
+        assert svc.stack.ingest is not None
+        svc.stop()
+    finally:
+        api._ACTIVE[0] = prev
+
+
+def test_python_backend_stack_has_nothing_to_warm():
+    svc = VerifyService.standalone()
+    assert svc.stack.backend is None
+    assert svc.stack.warm(svc.compiled_sizes) == {}
+    svc.stop()
+
+
+@pytest.mark.parametrize("name", ["fake", "python"])
+def test_serve_tool_selects_the_named_backend(name):
+    """tools/serve.py's in-process entry boots the daemon's service on
+    the backend ``--bls-backend`` names and verifies over HTTP with it."""
+    import os
+    import sys
+
+    from lighthouse_tpu.crypto.bls import api
+
+    sys.path.insert(0, os.path.join(os.path.dirname(
+        os.path.dirname(os.path.abspath(__file__))), "tools"))
+    import serve as serve_tool
+
+    prev = api.get_backend()
+    try:
+        svc, server, boot = serve_tool.start(
+            ["--port", "0", "--bls-backend", name])
+        try:
+            assert api.get_backend().name == name
+            assert boot["backend"] == name and boot["count"] == 1
+            assert boot["warm_seconds"] == {}
+            status, doc = _post(server.port,
+                                {"tenant": "t", "sets": _wire_sets(1)})
+            assert status == 202
+            svc.flush()
+            _, out = _get(server.port,
+                          f"/eth/v1/verify/batch/{doc['data']['request_id']}")
+            assert out["data"]["verdicts"] == [True]
+        finally:
+            server.stop()
+            svc.stop()
+    finally:
+        api._ACTIVE[0] = prev
+
+
+def test_pubkey_decode_cache_returns_one_object_per_key():
+    from lighthouse_tpu.crypto.bls.api import SecretKey
+    from lighthouse_tpu.serve.http import PubkeyDecodeCache
+
+    cache = PubkeyDecodeCache(capacity=2)
+    raw = [SecretKey(70 + i).public_key().to_bytes() for i in range(3)]
+    first = cache.decode(raw[0])
+    assert cache.decode(raw[0]) is first
+    assert first.to_bytes() == raw[0]
+    cache.decode(raw[1])
+    cache.decode(raw[2])  # evicts the least recently used: raw[0]
+    assert cache.decode(raw[0]) is not first
+    with pytest.raises(Exception):
+        cache.decode(b"\x00" * 48)
+    assert len(cache._keys) == 2

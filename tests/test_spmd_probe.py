@@ -201,7 +201,7 @@ class TestRegistryGatherProbe:
         import jax
         from jax.sharding import PartitionSpec as PS
 
-        from lighthouse_tpu.parallel.mesh import compat_shard_map
+        from lighthouse_tpu.parallel.mesh import shard_map
 
         def local(reg_x, reg_y, slots_local):
             x, y = P._registry_gather_local(
@@ -213,7 +213,7 @@ class TestRegistryGatherProbe:
             y = jax.lax.all_gather(y, BATCH_AXIS, axis=1, tiled=True)
             return x, y
 
-        return compat_shard_map(
+        return shard_map(
             local, mesh,
             in_specs=(PS(None, BATCH_AXIS), PS(None, BATCH_AXIS),
                       PS(BATCH_AXIS)),

@@ -548,7 +548,7 @@ def test_live_kernels_prove_no_uint32_overflow(live_range_fast):
         [str(v) for v in violations]
     )
     # the interpreter actually walked the kernels
-    assert report["programs"]["pallas_mont_mul"]["eqns"] > 1000
+    assert report["programs"]["pallas_mont_mul"]["eqns"] > 500
 
 
 def test_live_mont_kernels_prove_strict_contract(live_range_fast):

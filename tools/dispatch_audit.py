@@ -27,9 +27,9 @@ programs.  Violations — or a watchdog timeout while tracing a
 budget-critical config — exit nonzero.
 
 Usage:
-    tools/pyrun tools/dispatch_audit.py            # default matrix
-    tools/pyrun tools/dispatch_audit.py --quick    # budget-critical only
-    tools/pyrun tools/dispatch_audit.py --full     # + slow stacked-op trace
+    JAX_PLATFORMS=cpu python tools/dispatch_audit.py            # default matrix
+    JAX_PLATFORMS=cpu python tools/dispatch_audit.py --quick    # budget-critical only
+    JAX_PLATFORMS=cpu python tools/dispatch_audit.py --full     # + slow stacked-op trace
 """
 
 from __future__ import annotations

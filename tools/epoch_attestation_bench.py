@@ -81,9 +81,11 @@ def build_epoch_batch(n_sets: int, committee: int, pool: int):
 def run(n_sets: int, committee: int, pool: int, iters: int) -> dict:
     import jax
 
-    from __graft_entry__ import _enable_compile_cache
+    from lighthouse_tpu.crypto.bls.jax_backend.backend import (
+        enable_compile_cache,
+    )
 
-    _enable_compile_cache(jax)
+    enable_compile_cache()
     from lighthouse_tpu.crypto.bls.hash_to_curve import hash_to_g2
     from lighthouse_tpu.crypto.bls.jax_backend import points as P
     from lighthouse_tpu.crypto.bls.jax_backend.backend import (

@@ -42,15 +42,14 @@ def main() -> None:
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", 8)
-    except AttributeError:
-        pass  # older jax: rely on XLA_FLAGS=--xla_force_host_platform_device_count
+    jax.config.update("jax_num_cpu_devices", 8)
     import numpy as np
 
-    import __graft_entry__ as graft
+    from lighthouse_tpu.crypto.bls.jax_backend.backend import (
+        enable_compile_cache,
+    )
 
-    graft._enable_compile_cache(jax)
+    enable_compile_cache()
     from jax.sharding import Mesh
 
     from lighthouse_tpu.crypto.bls.jax_backend.backend import _verify_kernel

@@ -12,14 +12,14 @@ re-running the same name with the same seed replays the identical run
 (the fingerprint line must match).
 
 Usage:
-    tools/pyrun tools/scenario_run.py --list
-    tools/pyrun tools/scenario_run.py --scenario smoke
-    tools/pyrun tools/scenario_run.py --scenario mainnet-shape --json /tmp/r.json
-    tools/pyrun tools/scenario_run.py --scenario mainnet-shape:seed=99 --no-history
-    tools/pyrun tools/scenario_run.py --scenario slashing-flood --repeat 3
-    tools/pyrun tools/scenario_run.py --scenario long-non-finality --repeat 2
-    tools/pyrun tools/scenario_run.py --scenario hostile-checkpoint-sync
-    tools/pyrun tools/scenario_run.py --scenario registry-pressure
+    JAX_PLATFORMS=cpu python tools/scenario_run.py --list
+    JAX_PLATFORMS=cpu python tools/scenario_run.py --scenario smoke
+    JAX_PLATFORMS=cpu python tools/scenario_run.py --scenario mainnet-shape --json /tmp/r.json
+    JAX_PLATFORMS=cpu python tools/scenario_run.py --scenario mainnet-shape:seed=99 --no-history
+    JAX_PLATFORMS=cpu python tools/scenario_run.py --scenario slashing-flood --repeat 3
+    JAX_PLATFORMS=cpu python tools/scenario_run.py --scenario long-non-finality --repeat 2
+    JAX_PLATFORMS=cpu python tools/scenario_run.py --scenario hostile-checkpoint-sync
+    JAX_PLATFORMS=cpu python tools/scenario_run.py --scenario registry-pressure
 """
 
 from __future__ import annotations

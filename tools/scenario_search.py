@@ -20,11 +20,11 @@ as a JSON fixture in the committed regression corpus
 replays it standalone.
 
 Usage:
-    tools/pyrun tools/scenario_search.py --budget 32 --seed 7
-    tools/pyrun tools/scenario_search.py --corpus smoke --corpus long-non-finality
-    tools/pyrun tools/scenario_search.py --budget 8 --json /tmp/search.json
-    tools/pyrun tools/scenario_search.py --tracks device-faults --no-history
-    tools/pyrun tools/scenario_search.py --budget-seconds 60 --corpus smoke
+    JAX_PLATFORMS=cpu python tools/scenario_search.py --budget 32 --seed 7
+    JAX_PLATFORMS=cpu python tools/scenario_search.py --corpus smoke --corpus long-non-finality
+    JAX_PLATFORMS=cpu python tools/scenario_search.py --budget 8 --json /tmp/search.json
+    JAX_PLATFORMS=cpu python tools/scenario_search.py --tracks device-faults --no-history
+    JAX_PLATFORMS=cpu python tools/scenario_search.py --budget-seconds 60 --corpus smoke
 """
 
 from __future__ import annotations

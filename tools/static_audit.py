@@ -22,19 +22,19 @@ budget, zero-dim guard) live in the same package
 ``tools/dispatch_audit.py`` and the test suite.
 
 Usage:
-    tools/pyrun tools/static_audit.py                 # whole repo
-    tools/pyrun tools/static_audit.py --quiet         # summary line only
-    tools/pyrun tools/static_audit.py --only lock,raise,registry,jaxpr
+    JAX_PLATFORMS=cpu python tools/static_audit.py                 # whole repo
+    JAX_PLATFORMS=cpu python tools/static_audit.py --quiet         # summary line only
+    JAX_PLATFORMS=cpu python tools/static_audit.py --only lock,raise,registry,jaxpr
                                                       # fast AST tier
-    tools/pyrun tools/static_audit.py --only range    # kernel proofs only
-    tools/pyrun tools/static_audit.py --only spmd     # sharded-program proofs
-    tools/pyrun tools/static_audit.py --changed       # families scoped to
+    JAX_PLATFORMS=cpu python tools/static_audit.py --only range    # kernel proofs only
+    JAX_PLATFORMS=cpu python tools/static_audit.py --only spmd     # sharded-program proofs
+    JAX_PLATFORMS=cpu python tools/static_audit.py --changed       # families scoped to
                                                       # the git diff vs HEAD
-    tools/pyrun tools/static_audit.py --write-range-report
+    JAX_PLATFORMS=cpu python tools/static_audit.py --write-range-report
                                                       # refresh RANGE_REPORT.json
-    tools/pyrun tools/static_audit.py --no-cache      # fresh range traces
+    JAX_PLATFORMS=cpu python tools/static_audit.py --no-cache      # fresh range traces
                                                       # (skip .range_proof_cache.json)
-    tools/pyrun tools/static_audit.py --paths tests/fixtures/lint \\
+    JAX_PLATFORMS=cpu python tools/static_audit.py --paths tests/fixtures/lint \\
         --config tests/fixtures/lint/lint.toml        # fixture corpus
 """
 

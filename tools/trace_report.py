@@ -15,9 +15,9 @@ least one event, and attribute 100% of its wall time to known stages
 iff all three hold.
 
 Usage:
-    tools/pyrun tools/trace_report.py /tmp/trace.json
-    tools/pyrun tools/trace_report.py --json /tmp/trace.json
-    tools/pyrun tools/trace_report.py --check tests/fixtures/trace/pipeline_trace.json
+    JAX_PLATFORMS=cpu python tools/trace_report.py /tmp/trace.json
+    JAX_PLATFORMS=cpu python tools/trace_report.py --json /tmp/trace.json
+    JAX_PLATFORMS=cpu python tools/trace_report.py --check tests/fixtures/trace/pipeline_trace.json
 """
 
 from __future__ import annotations

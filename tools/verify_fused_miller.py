@@ -21,9 +21,11 @@ jax.config.update("jax_platforms", "cpu")
 
 import numpy as np  # noqa: E402
 
-import __graft_entry__ as graft  # noqa: E402
+from lighthouse_tpu.crypto.bls.jax_backend.backend import (  # noqa: E402
+    enable_compile_cache,
+)
 
-graft._enable_compile_cache(jax)
+enable_compile_cache()
 
 from lighthouse_tpu.crypto.bls import params  # noqa: E402
 from lighthouse_tpu.crypto.bls.curve import (  # noqa: E402
