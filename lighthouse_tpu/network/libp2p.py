@@ -593,12 +593,15 @@ class Libp2pHost:
             except (Libp2pError, NoiseError, OSError):
                 return b""
 
+        # the handshake is over: drop its timeout BEFORE the muxer's
+        # reader starts, or the reader's recv keeps it and closes any
+        # connection idle for 10 s (under load, even before this thread
+        # got back here to clear it)
+        sock.settimeout(None)
         muxer = Session(n_write, mux_recv, is_dialer=dialer,
                         on_stream=None)
         conn = Connection(self, sock, noise, muxer)
-        conn = self._adopt_connection(conn, expected_peer_id)
-        sock.settimeout(None)
-        return conn
+        return self._adopt_connection(conn, expected_peer_id)
 
     def _adopt_connection(self, conn: Connection,
                           expected_peer_id: bytes | None) -> Connection:

@@ -856,6 +856,8 @@ class QuicConnection:
                 ("crypto", space.crypto_offset, msg))
             space.crypto_offset += len(msg)
         for level, (c_secret, s_secret) in self.tls.secrets.items():
+            if level in self._discarded_levels:
+                continue  # retired for good (RFC 9001 §4.9)
             mine, theirs = ((c_secret, s_secret) if self.is_client
                             else (s_secret, c_secret))
             if level not in self.send_keys:

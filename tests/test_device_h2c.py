@@ -1,13 +1,12 @@
 """Device map-to-curve vs the host oracle: SSWU, isogeny, cofactor
 clearing — full differential over random messages on the CPU mesh."""
 
+import jax
 import pytest
 
-from lighthouse_tpu.crypto.bls import params
 from lighthouse_tpu.crypto.bls.hash_to_curve import (
     hash_to_field_fp2,
     hash_to_g2,
-    iso_map,
     sswu,
 )
 from lighthouse_tpu.crypto.bls.jax_backend import h2c, points as P, tower as T
@@ -25,9 +24,19 @@ def u_values():
     return u0s, u1s
 
 
-def _decode_fp2_pair(xy):
-    xs = T.fp2_decode(xy[0]) if hasattr(T, "fp2_decode") else None
-    return xs
+@pytest.fixture(scope="module")
+def staged():
+    """``h2c`` with map_to_g2's stages (and the point addition between
+    them) compiled.  Run op by op, the cofactor-clearing ladders alone
+    take over ten minutes on a CPU; one jit around the whole map is a
+    single four-minute compile.  Staged, each stage compiles once and
+    the two SSWU calls share a program."""
+    mp = pytest.MonkeyPatch()
+    for name in ("sswu_g2", "iso_map_g2", "clear_cofactor_g2"):
+        mp.setattr(h2c, name, jax.jit(getattr(h2c, name)))
+    mp.setattr(P, "jac_add", jax.jit(P.jac_add, static_argnums=0))
+    yield h2c
+    mp.undo()
 
 
 def _fp2_to_ints(x2):
@@ -38,27 +47,27 @@ def _fp2_to_ints(x2):
     return list(zip(c0, c1))
 
 
-def test_sswu_matches_oracle(u_values):
-    u0s, _ = u_values
-    enc = T.fp2_encode(u0s)
-    x_dev, y_dev = h2c.sswu_g2(enc)
-    xs = _fp2_to_ints(x_dev)
-    ys = _fp2_to_ints(y_dev)
-    for i, u in enumerate(u0s):
-        ox, oy = sswu(u)
-        assert xs[i] == (ox.c0, ox.c1), f"sswu x mismatch msg {i}"
-        assert ys[i] == (oy.c0, oy.c1), f"sswu y mismatch msg {i}"
-
-
-def test_full_map_matches_oracle(u_values):
+def test_full_map_matches_oracle(u_values, staged):
     u0s, u1s = u_values
-    h_dev = h2c.map_to_g2(T.fp2_encode(u0s), T.fp2_encode(u1s))
+    h_dev = staged.map_to_g2(T.fp2_encode(u0s), T.fp2_encode(u1s))
     xs = _fp2_to_ints(h_dev[0])
     ys = _fp2_to_ints(h_dev[1])
     for i, m in enumerate(MSGS):
         hx, hy = hash_to_g2(m)
         assert xs[i] == (hx.c0, hx.c1), f"H(m) x mismatch msg {i}"
         assert ys[i] == (hy.c0, hy.c1), f"H(m) y mismatch msg {i}"
+
+
+def test_sswu_matches_oracle(u_values, staged):
+    u0s, _ = u_values
+    enc = T.fp2_encode(u0s)
+    x_dev, y_dev = staged.sswu_g2(enc)
+    xs = _fp2_to_ints(x_dev)
+    ys = _fp2_to_ints(y_dev)
+    for i, u in enumerate(u0s):
+        ox, oy = sswu(u)
+        assert xs[i] == (ox.c0, ox.c1), f"sswu x mismatch msg {i}"
+        assert ys[i] == (oy.c0, oy.c1), f"sswu y mismatch msg {i}"
 
 
 def test_host_u_encoding_is_cheap():

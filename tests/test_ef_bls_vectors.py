@@ -32,7 +32,7 @@ def _ensure_vectors():
             [sys.executable, os.path.join(
                 os.path.dirname(__file__), "..", "tools", "gen_bls_vectors.py"
             )],
-            check=True,
+            check=True, timeout=120,
         )
 
 

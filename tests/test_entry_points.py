@@ -24,7 +24,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def _run(script, tmp_path, **env):
     full = dict(os.environ, JAX_PLATFORMS="cpu", HOME=str(tmp_path), **env)
     return subprocess.run([sys.executable, os.path.join(ROOT, script)],
-                          capture_output=True, text=True, timeout=300,
+                          capture_output=True, text=True, timeout=120,
                           cwd=str(tmp_path), env=full)
 
 

@@ -354,12 +354,15 @@ class TestIngestChaos:
 
 
 class TestMarshalBudget:
-    def test_vectorized_beats_scalar_10x_on_committee_shape(self):
+    def test_vectorized_beats_scalar_10x_on_committee_shape(
+        self, record_property
+    ):
         """Fast-tier regression tripwire (ISSUE 9 acceptance): on the
         epoch-processing shape — K=128 signers/set, repeat committees,
         warm cache — the vectorized marshal must hold >= 10x over the
-        per-set scalar loop.  Measured ~25x on this image; a slip below
-        10x means per-set Python crept back into the hot loop."""
+        per-set scalar loop.  Measured 11-16x (best of 5) on an 8-core
+        CPU host under the parallel tier-1 run; a slip below 10x means
+        per-set Python crept back into the hot loop."""
         K, n_c, B = 128, 16, 256
         pool_k = 16
         committees = [
@@ -376,17 +379,21 @@ class TestMarshalBudget:
         warm = eng.marshal_sets(sets, ws)  # populate cache, untimed
         assert not warm.invalid
 
-        t0 = time.perf_counter()
-        mb = eng.marshal_sets(sets, ws)
-        t_vec = time.perf_counter() - t0
+        # both arms timed alike and interleaved, best of several rounds:
+        # load from other processes then slows both, not one
+        t_vec = t_scalar = float("inf")
+        for _ in range(5):
+            t0 = time.perf_counter()
+            mb = eng.marshal_sets(sets, ws)
+            t_vec = min(t_vec, time.perf_counter() - t0)
+            t0 = time.perf_counter()
+            oracle = be.marshal_sets(sets, ws)
+            t_scalar = min(t_scalar, time.perf_counter() - t0)
         assert not mb.invalid
-
-        t0 = time.perf_counter()
-        oracle = be.marshal_sets(sets, ws)
-        t_scalar = time.perf_counter() - t0
 
         assert_mb_equal(oracle, mb, "budget-shape")
         speedup = t_scalar / t_vec
+        record_property("speedup", round(speedup, 2))  # junit XML
         assert speedup >= 10.0, (
             f"vectorized marshal only {speedup:.1f}x scalar "
             f"(scalar {B / t_scalar:.0f} sets/s, "

@@ -45,6 +45,8 @@ def _sock_reader(sock):
 def _noise_pair():
     """Run the XX handshake over a socketpair; returns both sessions."""
     sa, sb = socket.socketpair()
+    sa.settimeout(30)
+    sb.settimeout(30)
     ka = ec.generate_private_key(ec.SECP256K1())
     kb = ec.generate_private_key(ec.SECP256K1())
     result = {}

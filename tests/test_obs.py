@@ -113,7 +113,8 @@ class TestTracerConcurrency:
         for th in threads:
             th.start()
         for th in threads:
-            th.join()
+            th.join(timeout=30)
+            assert not th.is_alive()
         recs = t.snapshot()
         assert len(recs) == n_threads * per_thread
         assert t.dropped == 0

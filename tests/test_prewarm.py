@@ -233,7 +233,8 @@ def test_prewarm_concurrent_with_serving_sheds_nothing(tmp_path):
         svc.tick()
         if served >= 4096:  # liveness backstop, never expected
             break
-    t.join()
+    t.join(timeout=60)
+    assert not t.is_alive()
     svc.flush()
     assert sum(svc.admission.shed.get("client", {}).values()) == 0
     assert svc.completed.get("client", 0) == served

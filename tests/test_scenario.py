@@ -5,6 +5,9 @@ The fast tier runs the ``smoke`` scenario (3 nodes, 2 epochs, one fault
 track) twice to pin seed-determinism; the flagship ``mainnet-shape`` run
 and its breaker-disabled degraded twin are marked ``slow`` (they are the
 acceptance soaks ``tools/scenario_run.py`` drives in CI's long lane).
+The flight-recorder tests are in test_scenario_trace.py and the
+saturation regimes in test_scenario_saturation.py, so that a parallel
+run spreads the scenario runs over workers.
 """
 
 import json
@@ -151,11 +154,18 @@ class TestSLOEvaluate:
 # ---------------------------------------------------------------------------
 
 
-def test_smoke_scenario_passes_and_is_deterministic(tmp_path):
+@pytest.fixture(scope="module")
+def smoke_report():
+    """One canonical ``smoke`` run (about 20 s), read by every test here
+    that needs the canonical run rather than a run of its own."""
+    return run_scenario("smoke")
+
+
+def test_smoke_scenario_passes_and_is_deterministic(tmp_path, smoke_report):
     out = tmp_path / "report.json"
     hist = tmp_path / "history.jsonl"
     r1 = run_scenario("smoke", out_path=str(out), history_path=str(hist))
-    r2 = run_scenario("smoke")
+    r2 = smoke_report
     assert r1["pass"], [s for s in r1["slo"] if not s["ok"]]
     assert r2["pass"]
     # exact reproducibility: same seed => same fault sequence, same heads
@@ -178,15 +188,21 @@ def test_smoke_scenario_passes_and_is_deterministic(tmp_path):
     assert "slo_warnings" in on_disk
 
 
-def test_seed_override_changes_the_run(tmp_path):
+def test_seed_override_changes_the_run(smoke_report):
     spec = SCENARIOS["smoke"].with_seed(4321)
     assert isinstance(spec, ScenarioSpec) and spec.seed == 4321
     r = run_scenario(spec)
     # a different seed draws a different fault stream; the run still
     # reports honestly either way (pass is not asserted here — only
     # that the fingerprint diverges from the canonical seed's)
-    canonical = run_scenario("smoke")
-    assert r["fingerprint"] != canonical["fingerprint"]
+    assert r["fingerprint"] != smoke_report["fingerprint"]
+
+
+def test_smoke_run_reports_overlap_facts(smoke_report):
+    ov = smoke_report["facts"]["overlap_efficiency"]
+    assert ov["mode"] in ("pipeline", "serial", "empty")
+    if ov["ratio"] is not None:
+        assert ov["ratio"] > 0
 
 
 # ---------------------------------------------------------------------------
@@ -382,227 +398,6 @@ def test_bn_scenario_smoke_exits_zero():
 
 
 # ---------------------------------------------------------------------------
-# Flight-recorder integration: overlap gate, SLO-failure dumps, determinism
-# ---------------------------------------------------------------------------
-
-
-def test_overlap_gate_is_warn_level_and_never_flips_pass():
-    # blown overlap ratio -> the gate reports not-ok at warn level, and a
-    # run where it is the ONLY failure still counts as passing (the gate
-    # is a telemetry tripwire, not a verdict)
-    results = evaluate(
-        {"max_overlap_wall_ratio": 1.5}, {},
-        {"overlap_efficiency": {"ratio": 5.0, "mode": "pipeline"}},
-    )
-    (r,) = results
-    assert r.name == "overlap_efficiency" and not r.ok
-    assert r.level == "warn"
-    assert r.to_dict()["level"] == "warn"
-    assert all(x.ok for x in results if x.level == "fail")
-    # a missing ratio (nothing to attribute) never fires the gate
-    (r2,) = evaluate({"max_overlap_wall_ratio": 1.5}, {},
-                     {"overlap_efficiency": {"ratio": None, "mode": "empty"}})
-    assert r2.ok
-
-
-def test_mainnet_shape_carries_overlap_slo():
-    assert SCENARIOS["mainnet-shape"].slo["max_overlap_wall_ratio"] == 8.0
-    assert DEFAULT_SLO["max_overlap_wall_ratio"] is None  # off by default
-
-
-def test_smoke_run_reports_overlap_facts():
-    r = run_scenario("smoke")
-    ov = r["facts"]["overlap_efficiency"]
-    assert ov["mode"] in ("pipeline", "serial", "empty")
-    if ov["ratio"] is not None:
-        assert ov["ratio"] > 0
-
-
-def _failing_smoke_spec(seed=None):
-    from dataclasses import replace
-
-    spec = SCENARIOS["smoke"]
-    if seed is not None:
-        spec = spec.with_seed(seed)
-    # an unmeetable fail-level gate: the smoke run cannot detect 99
-    # slashings (it runs no equivocation track)
-    return replace(spec, slo={**spec.slo, "min_slashings_detected": 99})
-
-
-def test_failing_run_leaves_trace_dump_next_to_report(tmp_path):
-    out = tmp_path / "report.json"
-    r = run_scenario(_failing_smoke_spec(), out_path=str(out))
-    assert not r["pass"]
-    assert r["trace_dump"] == str(out) + ".trace.json"
-    doc = json.loads(open(r["trace_dump"]).read())
-    names = {ev["name"] for ev in doc["traceEvents"]}
-    assert "scenario.slot" in names
-    # the dump is scoped to THIS run: every slot of the spec, no more
-    slots = [ev for ev in doc["traceEvents"] if ev["name"] == "scenario.slot"]
-    assert len(slots) == r["slots"]
-    # the on-disk report references the artifact too
-    assert json.loads(out.read_text())["trace_dump"] == r["trace_dump"]
-
-
-def test_passing_run_has_no_trace_dump(tmp_path):
-    out = tmp_path / "report.json"
-    r = run_scenario("smoke", out_path=str(out))
-    assert r["pass"]
-    assert r["trace_dump"] is None
-    assert not (tmp_path / "report.json.trace.json").exists()
-
-
-def test_trace_dump_is_deterministic_under_fixed_seed(tmp_path):
-    from collections import Counter
-
-    spans = []
-    for i in range(2):
-        out = tmp_path / f"r{i}.json"
-        r = run_scenario(_failing_smoke_spec(seed=77), out_path=str(out))
-        doc = json.loads(open(r["trace_dump"]).read())
-        spans.append(Counter(ev["name"] for ev in doc["traceEvents"]))
-    # same seed => same work => the same span population, event for event
-    assert spans[0] == spans[1]
-    assert spans[0]["scenario.slot"] > 0
-
-
-# ---------------------------------------------------------------------------
-# Saturation soaks (ROADMAP item 5 follow-through): deposit-queue
-# saturation, adversarial aggregation storms, and the per-epoch SLO
-# snapshot machinery behind first_violation_epoch
-# ---------------------------------------------------------------------------
-
-from lighthouse_tpu.scenario.slo import EPOCH_GATED_KEYS, evaluate_epoch
-
-
-class TestEvaluateEpoch:
-    def test_epoch_gates_localize_the_three_soak_keys(self):
-        t = {"max_deposit_queue_depth": 10, "max_ssz_cache_bytes": 100,
-             "max_pool_estimated_verify_cost": 5}
-        results = evaluate_epoch(t, {
-            "deposit_queue_depth": 11, "ssz_cache_bytes": 50,
-            "pool_estimated_verify_cost": 5,
-        })
-        by_name = {r.name: r for r in results}
-        assert set(by_name) == {
-            "deposit_queue_depth", "ssz_cache_bytes", "pool_verify_cost"
-        }
-        assert not by_name["deposit_queue_depth"].ok
-        assert by_name["ssz_cache_bytes"].ok
-        assert by_name["pool_verify_cost"].ok  # at the limit is ok
-
-    def test_none_thresholds_produce_no_epoch_gates(self):
-        assert evaluate_epoch(dict(DEFAULT_SLO), {}) == []
-
-    def test_epoch_gated_keys_are_registered_thresholds(self):
-        assert set(EPOCH_GATED_KEYS) <= set(DEFAULT_SLO)
-
-
-# Pinned fingerprints for the saturation regimes.  The healthy and
-# weakened-drain deposit twins share traffic but not spec overrides, so
-# their fingerprints differ; the two storm twins differ only in the
-# serve admission cost model, which the fingerprint inputs (faults,
-# heads, finality) never see — identical fingerprints there prove the
-# admission knob is out of the consensus path.
-DEPOSIT_SATURATION_FINGERPRINT = "e25e57e52ab17be5"
-DEPOSIT_SATURATION_LAGGING_FINGERPRINT = "78eae5d1d5516fae"
-AGGREGATION_STORM_FINGERPRINT = "e5fb384b9a2bef1c"
-
-
-def test_deposit_saturation_drain_keeps_pace():
-    r = run_scenario("deposit-saturation")
-    assert r["pass"], [s for s in r["slo"] if not s["ok"]]
-    assert r["fingerprint"] == DEPOSIT_SATURATION_FINGERPRINT
-    assert r["first_violation_epoch"] is None
-    by_name = {s["name"]: s for s in r["slo"]}
-    # inflow really outran the drain (backlog grew) yet stayed in budget
-    assert 0 < by_name["deposit_queue_depth"]["observed"] <= 64
-    assert by_name["deposit_drain"]["observed"] >= 48
-    assert r["facts"]["deposits_queued"] > r["facts"]["deposits_applied"]
-    # per-epoch snapshots rode along, one per epoch, each with the
-    # epoch-localized gate verdicts
-    assert len(r["epochs"]) == SCENARIOS["deposit-saturation"].epochs
-    for rec in r["epochs"]:
-        assert {"epoch", "metrics", "facts", "slo"} <= set(rec)
-        assert "deposit_queue_depth" in rec["facts"]
-
-
-def test_deposit_saturation_lagging_fails_at_the_epoch_it_starts():
-    r = run_scenario("deposit-saturation-lagging")
-    assert not r["pass"]
-    assert r["fingerprint"] == DEPOSIT_SATURATION_LAGGING_FINGERPRINT
-    failed = [s["name"] for s in r["slo"] if not s["ok"]]
-    assert "deposit_queue_depth" in failed, failed
-    # the backlog first crosses the 64-deposit budget at epoch 3 (depths
-    # 2/25/65/105) — the report must localize the violation there
-    assert r["first_violation_epoch"] == 3
-    epoch3 = [e for e in r["epochs"] if e["epoch"] == 3][0]
-    bad = [g for g in epoch3["slo"] if not g["ok"]]
-    assert any(g["name"] == "deposit_queue_depth" for g in bad)
-
-
-def test_aggregation_storm_cost_model_sheds_the_overage():
-    r = run_scenario("aggregation-storm")
-    assert r["pass"], [s for s in r["slo"] if not s["ok"]]
-    assert r["fingerprint"] == AGGREGATION_STORM_FINGERPRINT
-    by_name = {s["name"]: s for s in r["slo"]}
-    # cost-priced admission shed the storm's near-duplicate overage...
-    assert by_name["storm_shed"]["observed"] >= 0.5
-    assert by_name["naive_pool_growth"]["ok"]
-    assert by_name["pool_verify_cost"]["ok"]
-    # ...without touching the honest tenant's deadlines
-    assert by_name["honest_deadline_misses"]["observed"] <= 0.02
-    assert r["facts"]["storm_admitted"] < r["facts"]["storm_submitted"]
-
-
-def test_aggregation_storm_uncosted_twin_fails_the_overload_gate():
-    r = run_scenario("aggregation-storm-uncosted")
-    assert not r["pass"]
-    # set-count admission admits everything: same consensus history
-    # (identical fingerprint), blown pool gates
-    assert r["fingerprint"] == AGGREGATION_STORM_FINGERPRINT
-    failed = [s["name"] for s in r["slo"] if not s["ok"]]
-    assert "naive_pool_growth" in failed and "pool_verify_cost" in failed
-    # uncosted pool cost crosses the 1024 budget at epoch 2 (504/1080/1656)
-    assert r["first_violation_epoch"] == 2
-
-
-# ---------------------------------------------------------------------------
-# The committed regression corpus: search findings replay standalone
-# ---------------------------------------------------------------------------
-
-REGRESS_FIXTURE = "regress-deposit_queue_depth-deposit_drain-586964"
-
-
-def test_committed_fixture_resolves_through_parse_scenario_arg():
-    spec = parse_scenario_arg(REGRESS_FIXTURE)
-    assert spec.name == REGRESS_FIXTURE and spec.seed == 586964
-    # overrides compose with fixture resolution like registry names
-    assert parse_scenario_arg(f"{REGRESS_FIXTURE}:seed=5").seed == 5
-
-
-def test_committed_fixture_replays_its_violation_standalone():
-    r = run_scenario(parse_scenario_arg(REGRESS_FIXTURE))
-    assert not r["pass"]
-    assert r["fingerprint"] == "a606c5b6dfbc2284"
-    failed = [s["name"] for s in r["slo"] if not s["ok"]]
-    assert failed == ["deposit_drain"]
-
-
-def test_fixture_round_trip_and_validation():
-    from lighthouse_tpu.scenario.spec import spec_from_json, spec_to_json
-
-    spec = SCENARIOS["deposit-saturation"]
-    assert spec_from_json(spec_to_json(spec)) == spec
-    with pytest.raises(ValueError, match="unknown scenario fixture field"):
-        spec_from_json({"name": "x", "seed": 1, "frobnicate": 2})
-    with pytest.raises(ValueError, match="missing 'seed'"):
-        spec_from_json({"name": "x"})
-    with pytest.raises(ValueError, match="unregistered SLO"):
-        spec_from_json({"name": "x", "seed": 1, "slo": {"max_bogus": 1}})
-
-
-# ---------------------------------------------------------------------------
 # tools/scenario_run.py --repeat: per-epoch SLO snapshot diffing
 # ---------------------------------------------------------------------------
 
@@ -686,45 +481,3 @@ class TestScenarioRunEpochDiff:
         assert rc == 0
         assert "per-epoch SLO snapshots stable over 2 runs" in \
             capsys.readouterr().out
-
-
-# ---------------------------------------------------------------------------
-# The 1M-validator multi-epoch soak (slow tier, `pytest -m soak`):
-# registry-pressure's copy-on-write trick stretched 10x, with the SSZ
-# byte budget as a hard per-epoch SLO and a host peak-memory pin.
-# ---------------------------------------------------------------------------
-
-
-SOAK_1M_FINGERPRINT = "60080233cf7934a2"
-
-
-@pytest.mark.slow
-@pytest.mark.soak
-def test_soak_1m_multi_epoch_within_cache_budget():
-    """Three epochs over a 1,000,000-validator registry: the run passes
-    every deterministic gate, each per-epoch snapshot stays inside the
-    SSZ byte budget (a slow leak would fail at the epoch it starts),
-    and host peak memory stays bounded.
-
-    Peak memory is pinned via ru_maxrss rather than tracemalloc:
-    tracing roughly doubles this run's ~7-minute wall time for no
-    extra signal — the registry's big allocations are numpy planes
-    that RSS captures just as well (measured 10.5 GiB on this image).
-    """
-    import resource
-
-    r = run_scenario("soak-1m")
-    assert r["pass"], [s["name"] for s in r["slo"] if not s["ok"]]
-    assert r["fingerprint"] == SOAK_1M_FINGERPRINT
-    assert r.get("first_violation_epoch") is None
-
-    budget = 268_435_456  # mirrors the registered max_ssz_cache_bytes
-    epochs = r["epochs"]
-    assert len(epochs) == 3
-    for rec in epochs:
-        assert 0 < rec["facts"]["ssz_cache_bytes"] <= budget, rec
-        gates = {g["name"]: g["ok"] for g in rec["slo"]}
-        assert gates.get("ssz_cache_bytes", True), rec
-
-    peak_mib = resource.getrusage(resource.RUSAGE_SELF).ru_maxrss / 1024
-    assert peak_mib < 14 * 1024, f"host peak {peak_mib:.0f} MiB"

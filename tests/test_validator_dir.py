@@ -3,16 +3,15 @@ a definitions manifest, and LOCKFILES that stop two processes signing
 with the same keys (common/validator_dir + common/lockfile +
 initialized_validators.rs)."""
 
-import json
-import multiprocessing
 import os
+import subprocess
+import sys
 
 import pytest
 
 from lighthouse_tpu.crypto import keystore as ks
 from lighthouse_tpu.crypto.bls.api import SecretKey
 from lighthouse_tpu.validator.validator_dir import (
-    Lockfile,
     LockfileError,
     ValidatorDirManager,
 )
@@ -56,14 +55,17 @@ def test_stale_lock_reclaimed(tmp_path):
     mgr = ValidatorDirManager(str(tmp_path))
     store = _keystore(2)
     v = mgr.create(store)
-    # a dead process's pid in the lockfile must not brick the keys
-    def hold(path):
-        Lockfile(path).acquire()
-        os._exit(0)  # die WITHOUT releasing
-
-    p = multiprocessing.Process(target=hold, args=(v.lock.path,))
-    p.start()
-    p.join()
+    # a dead process's pid in the lockfile must not brick the keys: a
+    # fresh interpreter (not a fork of this one and its threads) takes
+    # the lock and dies WITHOUT releasing it
+    hold = ("import os, sys; "
+            "from lighthouse_tpu.validator.validator_dir import Lockfile; "
+            "Lockfile(sys.argv[1]).acquire(); os._exit(0)")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.run([sys.executable, "-c", hold, v.lock.path],
+                          cwd=root, capture_output=True, text=True,
+                          timeout=60)
+    assert proc.returncode == 0, proc.stderr
     assert os.path.exists(v.lock.path)
     v2 = mgr.open_validator(store["pubkey"])  # reclaims
     v2.lock.release()

@@ -1,19 +1,27 @@
 #!/usr/bin/env python3
-"""Standalone fused-Miller equality proof (invoked by
+"""Standalone fused-Miller equality proofs (invoked by
 tests/test_pallas_miller.py in a SUBPROCESS: the eager proof is stable
 in a fresh interpreter but segfaults inside a long pytest process that
 already ran ~80 JAX compiles — an XLA:CPU process-state crash, not a
 kernel bug; isolation sidesteps it and matches how the kernels run in
 production anyway: one process, one trace).
 
-Checks dbl half + add half (both bit arms, chained on live outputs)
-against the XLA formulas, canonical equality on every lane."""
+Default mode: the dbl half and the add half (both bit arms, chained on
+live outputs) against the XLA formulas, canonical equality on every
+lane.  The kernel bodies run on plain arrays with their Montgomery
+cores compiled once each (see ``run_kernel``): about 40 s on an 8-core
+CPU host.  The same halves as interpret-mode ``pallas_call`` programs
+(``--step``, and the full loop in ``--loop``/``--bilinear``) are one
+XLA:CPU compile of ~10^5 primitives each: ``--step`` took 47 minutes
+on that host."""
 
 import os
 import sys
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import contextlib  # noqa: E402
 
 import jax  # noqa: E402
 
@@ -38,6 +46,9 @@ from lighthouse_tpu.crypto.bls.curve import (  # noqa: E402
 from lighthouse_tpu.crypto.bls.jax_backend import fp as F  # noqa: E402
 from lighthouse_tpu.crypto.bls.jax_backend import pairing as JP  # noqa: E402
 from lighthouse_tpu.crypto.bls.jax_backend import (  # noqa: E402
+    pallas_fp as PF,
+)
+from lighthouse_tpu.crypto.bls.jax_backend import (  # noqa: E402
     pallas_miller as PM,
 )
 from lighthouse_tpu.crypto.bls.jax_backend import points as P  # noqa: E402
@@ -46,8 +57,8 @@ from lighthouse_tpu.crypto.bls.jax_backend import tower as T  # noqa: E402
 
 def build_fixture():
     """Shared inputs + reference values for the equality proofs (used by
-    main() below AND tests/test_pallas_miller.py — ONE copy of the lane
-    layout, so a kernel-signature change cannot desynchronize them)."""
+    main() and step_proof() — ONE copy of the lane layout, so a
+    kernel-signature change cannot desynchronize them)."""
     pairs = [
         (affine_mul(G1_GENERATOR, 20250730, Fp),
          affine_mul(G2_GENERATOR, 424242, Fp2)),
@@ -71,18 +82,18 @@ def build_fixture():
     )
     Tpt = (q0, q1, one2)
 
-    # XLA halves (eager)
-    line, T2 = JP._line_dbl(Tpt, xp, yp)
-    ref_f_mid = T.fp12_mul_by_023(T.fp12_sqr(f), *line)
-    ref_T_mid = T2
+    # XLA halves, one compiled program (eagerly, every stacked mont_mul
+    # shape would compile on its own: ~5x slower)
+    @jax.jit
+    def xla_halves(f, Tpt, xp, yp, q0, q1):
+        line, T_mid = JP._line_dbl(Tpt, xp, yp)
+        f_mid = T.fp12_mul_by_023(T.fp12_sqr(f), *line)
+        line_a, T_add = JP._line_add(T_mid, (q0, q1), xp, yp)
+        return f_mid, T_mid, T.fp12_mul_by_023(f_mid, *line_a), T_add
 
-    def xla_add(fv, Tv, take):
-        line_a, T_add = JP._line_add(Tv, (q0, q1), xp, yp)
-        f_a = T.fp12_mul_by_023(fv, *line_a)
-        return (f_a if take else fv), (T_add if take else Tv)
-
-    ref_f1, ref_T1 = xla_add(ref_f_mid, ref_T_mid, True)
-    ref_f0, ref_T0 = xla_add(ref_f_mid, ref_T_mid, False)
+    ref_f_mid, ref_T_mid, ref_f1, ref_T1 = xla_halves(f, Tpt, xp, yp, q0, q1)
+    # bit = 0: the add half keeps its inputs
+    ref_f0, ref_T0 = ref_f_mid, ref_T_mid
 
     def flat(x):
         return x.limbs.reshape(F.N, -1)
@@ -209,22 +220,68 @@ def bilinear_proof() -> None:
     print("fused-miller bilinear OK")
 
 
+class ArrayRef:
+    """Stands in for a kernel Ref on a plain array: ``r[:]`` reads the
+    whole block, ``r[:] = v`` replaces it."""
+
+    def __init__(self, value=None):
+        self.value = value
+
+    def __getitem__(self, idx):
+        return self.value[idx]
+
+    def __setitem__(self, idx, value):
+        assert idx == slice(None), idx
+        self.value = value
+
+
+@contextlib.contextmanager
+def compiled_cores():
+    """The kernels' VPU Montgomery cores as two compiled programs.  Run
+    op by op, the ~160 unrolled products of a half are ~10^5 dispatches;
+    compiled, they are ~160 calls of two small programs."""
+    mont, sqr = jax.jit(PF._mont_core), jax.jit(PF._mont_sqr_core)
+    saved = PF._core_pair
+
+    def core_pair(mxu):
+        assert not mxu
+        return mont, sqr
+
+    PF._core_pair = core_pair
+    try:
+        yield
+    finally:
+        PF._core_pair = saved
+
+
+def run_kernel(kernel, ins, n_out):
+    """One grid step of ``kernel`` on arrays that are exactly one block:
+    the body ``pallas_call`` traces, evaluated without the one giant
+    interpret-mode compile."""
+    outs = [ArrayRef() for _ in range(n_out)]
+    with compiled_cores():
+        kernel(*[ArrayRef(a) for a in ins], *outs)
+    return [r.value for r in outs]
+
+
 def main() -> None:
     fx = build_fixture()
     f_arr, T_arr, q_arr = fx["f_arr"], fx["T_arr"], fx["q_arr"]
     xp_a, yp_a, consts = fx["xp_a"], fx["yp_a"], fx["consts"]
-    n_padded, tile = fx["n_padded"], fx["tile"]
-    dbl = PM._dbl_call(n_padded, tile, True)
-    add = PM._add_call(n_padded, tile, True)
+    n_padded = fx["n_padded"]
+    assert n_padded == fx["tile"], "one block per operand"
+    n_out = len(f_arr) + len(T_arr)
 
-    mid = dbl(*f_arr, *T_arr, xp_a, yp_a, *consts)
+    mid = run_kernel(PM._step_dbl_kernel,
+                     [*f_arr, *T_arr, xp_a, yp_a, *consts], n_out)
 
     def run_add(bit):
         import jax.numpy as jnp
 
         bit_row = jnp.full((1, n_padded), bit, dtype=jnp.uint32)
-        return add(*list(mid[:12]), *list(mid[12:]), *q_arr, xp_a, yp_a,
-                   bit_row, *consts)
+        return run_kernel(PM._step_add_kernel,
+                          [*mid[:12], *mid[12:], *q_arr, xp_a, yp_a,
+                           bit_row, *consts], n_out)
 
     out1 = run_add(1)
     out0 = run_add(0)
