@@ -6,12 +6,11 @@ encode — for every signature set and pins one core.  This package makes
 operand preparation a first-class subsystem in front of the wide verify
 unit:
 
-* :mod:`.sha` — batched SHA-256 / RFC 9380 expand_message_xmd lanes:
-  one numpy op per hash round for the whole batch;
 * :mod:`.cache` — device-resident pubkey limb cache (registry tier keyed
   by validator index + epoch-scoped aggregate LRU): repeat signers skip
   aggregation and limb encode;
-* :mod:`.pool` — core-scaling shard pool for the numpy stages;
+* :mod:`.pool` — core-scaling shard pool for numpy stages (no stage
+  uses it today);
 * :mod:`.engine` — :class:`IngestEngine`, the never-raise
   ``marshal_sets`` front-end, byte-identical to the scalar oracle and
   degrading to it on any failure.

@@ -4,11 +4,11 @@
 hashing, per-set pubkey aggregation, per-set limb encode — so the host
 feed path pins one core at ~5k sets/s while the device verifies 6.2k.
 ``IngestEngine.marshal_sets`` produces a **byte-identical**
-``MarshalledBatch`` from three vectorized stages:
+``MarshalledBatch`` from three stages:
 
-1. *expand* — all message hash-to-field draws run through the batched
-   SHA-256 lanes (:mod:`.sha`), sharded across host cores by the
-   :class:`~lighthouse_tpu.ingest.pool.MarshalPool`;
+1. *expand* — each *unique* message's hash-to-field draw runs once,
+   through the scalar hashlib ``hash_to_field_fp2``, and fans out to
+   every set that signs it;
 2. *cache*  — aggregated-pubkey limb columns come from the
    :class:`~lighthouse_tpu.ingest.cache.PubkeyLimbCache`; repeat signers
    (registry validators, warm committees) skip host aggregation and limb
@@ -37,6 +37,7 @@ import time
 import numpy as np
 
 from ..crypto.bls import params
+from ..crypto.bls.hash_to_curve import hash_to_field_fp2
 from ..crypto.bls.jax_backend import fp as F
 from ..crypto.bls.jax_backend import points as P
 from ..crypto.bls.jax_backend import tower as T
@@ -47,7 +48,6 @@ from ..utils import metrics as M
 from ..utils.logging import get_logger
 from .cache import PubkeyLimbCache
 from .pool import MarshalPool
-from .sha import hash_to_field_fp2_batch
 
 log = get_logger("ingest.engine")
 
@@ -211,10 +211,10 @@ class IngestEngine:
                 if slots_arr is None:
                     args = (pk_operand,) + args
             else:
-                # Host hash-to-curve: the field draws still run through
-                # the batched SHA lanes; the curve steps (SSWU, isogeny,
-                # cofactor) reuse the scalar building blocks hash_to_g2
-                # composes, so outputs stay identical.
+                # Host hash-to-curve: the field draws are deduplicated as
+                # above; the curve steps (SSWU, isogeny, cofactor) reuse
+                # the scalar building blocks hash_to_g2 composes, so
+                # outputs stay identical.
                 from ..crypto.bls.curve import affine_add
                 from ..crypto.bls.endo import clear_cofactor_fast
                 from ..crypto.bls.fields import Fp2
@@ -249,14 +249,10 @@ class IngestEngine:
         message once.  Committee fan-out re-signs one signing root per
         committee across many sets; the scalar oracle re-hashes it per
         set, but hashing is a pure function of the message, so fan-out
-        after deduplication yields the identical values."""
-        uniq = list(dict.fromkeys(msgs))
-        us_u = self.pool.map_shards(
-            lambda ms: hash_to_field_fp2_batch(ms, 2), uniq
-        )
-        if len(uniq) == len(msgs):
-            return us_u
-        by_msg = dict(zip(uniq, us_u))
+        after deduplication yields the identical values.  Inline, not
+        sharded: hashlib holds the GIL for inputs under 2 KiB, so
+        threads cannot help."""
+        by_msg = {m: hash_to_field_fp2(m, 2) for m in set(msgs)}
         return [by_msg[m] for m in msgs]
 
     def _registry_slots(self, sets, reps: int):

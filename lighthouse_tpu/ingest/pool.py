@@ -1,6 +1,6 @@
 """Core-scaling marshal worker pool: shard a batch across host threads.
 
-The vectorized stages (batched SHA-256, limb encode) are numpy-dominated,
+Vectorized stages such as limb encode are numpy-dominated,
 and numpy releases the GIL inside its ufunc loops, so sharding a large
 batch across threads scales the marshal stage with host cores instead of
 pinning one — without the pickling cost a process pool would pay to ship
