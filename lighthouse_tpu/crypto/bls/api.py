@@ -12,6 +12,10 @@ per_block_processing/signature_sets.rs).
 Semantics mirrored from the reference:
   * PublicKey deserialization rejects the point at infinity and runs
     key_validate (crypto/bls/src/generic_public_key.rs:14-15,70).
+    ``PublicKey.from_bytes_unchecked`` defers the subgroup half of
+    key_validate, as ``Signature.from_bytes`` defers its own check; the
+    key carries the check with it, and every verification engine runs it
+    (or has it run on the device) before the key counts.
   * `verify_signature_sets` draws nonzero 64-bit random weights per set,
     subgroup-checks signatures, rejects empty sets, aggregates each set's
     pubkeys, and performs one multi-pairing check
@@ -35,6 +39,7 @@ from .curve import (
     affine_neg,
     from_jacobian,
     g1_from_bytes,
+    g1_subgroup_check,
     g1_to_bytes,
     g2_from_bytes,
     g2_subgroup_check,
@@ -90,21 +95,49 @@ class SecretKey:
 
 
 class PublicKey:
-    """A validated, non-infinity G1 point (decompressed)."""
+    """A non-infinity G1 point (decompressed).  Subgroup-checked, unless
+    decoded by ``from_bytes_unchecked``: such a key holds no verdict
+    until ``subgroup_check`` runs on the host or a batched device check
+    records one (``record_subgroup_check``); the verdict, either way, is
+    kept on the object."""
 
-    __slots__ = ("point",)
+    __slots__ = ("point", "_in_g1")
 
-    def __init__(self, point):
+    def __init__(self, point, subgroup_checked: bool = True):
         if point is None:
             # Reference rejects infinity pubkeys at deserialize
             # (generic_public_key.rs:14-15).
             raise BlsError("public key cannot be the point at infinity")
         self.point = point
+        # True / False once checked, None while the check is pending
+        self._in_g1 = True if subgroup_checked else None
 
     @classmethod
     def from_bytes(cls, data: bytes) -> "PublicKey":
         pt = g1_from_bytes(data, subgroup_check=True)
         return cls(pt)
+
+    @classmethod
+    def from_bytes_unchecked(cls, data: bytes) -> "PublicKey":
+        """Decompress only (on-curve, not infinity); the subgroup check
+        is left to whoever verifies with the key."""
+        pt = g1_from_bytes(data, subgroup_check=False)
+        return cls(pt, subgroup_checked=False)
+
+    @property
+    def subgroup_checked(self) -> bool:
+        """Whether the key's subgroup verdict is known."""
+        return self._in_g1 is not None
+
+    def subgroup_check(self) -> bool:
+        """The key lies in G1: the kept verdict, else the host check."""
+        if self._in_g1 is None:
+            self._in_g1 = g1_subgroup_check(self.point)
+        return self._in_g1
+
+    def record_subgroup_check(self, in_g1: bool) -> None:
+        """Keep a verdict computed elsewhere (the device key check)."""
+        self._in_g1 = bool(in_g1)
 
     def to_bytes(self) -> bytes:
         return g1_to_bytes(self.point)
@@ -224,9 +257,29 @@ class SignatureSet:
     signature: Signature
     signing_keys: list[PublicKey]
     message: bytes  # raw message (for Ethereum: a 32-byte signing root)
+    # whether some signing key was not known to lie in G1 when the set
+    # was built (decoded unchecked, or checked and rejected): the one
+    # flag a verifier reads to skip the per-key walk for every other set
+    keys_unvalidated: bool = field(init=False, default=False, compare=False,
+                                   repr=False)
+
+    def __post_init__(self):
+        self.keys_unvalidated = any(getattr(pk, "_in_g1", True) is not True
+                                    for pk in self.signing_keys)
 
     def verify(self) -> bool:
         return get_backend().verify_signature_sets([self])
+
+    def keys_valid(self) -> bool:
+        """Every signing key lies in G1: at once when the set was built
+        from checked keys, else key by key (``PublicKey.subgroup_check``,
+        the host check for a key no one has checked yet)."""
+        if not self.keys_unvalidated:
+            return True
+        if not all(pk.subgroup_check() for pk in self.signing_keys):
+            return False
+        self.keys_unvalidated = False
+        return True
 
 
 # ---------------------------------------------------------------------------
@@ -242,7 +295,7 @@ class PythonBackend:
     def verify(self, pubkey: PublicKey, msg: bytes, sig: Signature) -> bool:
         if sig.point is None:
             return False
-        if not sig.subgroup_check():
+        if not sig.subgroup_check() or not pubkey.subgroup_check():
             return False
         h = hash_to_g2(msg)
         return pairing_check(
@@ -257,6 +310,8 @@ class PythonBackend:
             return False
         if sig.point is None or not sig.subgroup_check():
             return False
+        if not all(pk.subgroup_check() for pk in pubkeys):
+            return False
         pairs = [(affine_neg(G1_GENERATOR), sig.point)]
         for pk, m in zip(pubkeys, msgs):
             pairs.append((pk.point, hash_to_g2(m)))
@@ -269,6 +324,8 @@ class PythonBackend:
         if not pubkeys:
             return False
         if sig.point is None or not sig.subgroup_check():
+            return False
+        if not all(pk.subgroup_check() for pk in pubkeys):
             return False
         agg = AggregatePublicKey.aggregate(pubkeys)
         if agg.point is None:
@@ -295,6 +352,10 @@ class PythonBackend:
             if not s.signature.subgroup_check():
                 return False
             if not s.signing_keys:
+                return False
+            # key_validate for keys decoded unchecked (the reference's
+            # blst validates every key before it aggregates)
+            if not all(pk.subgroup_check() for pk in s.signing_keys):
                 return False
             agg = AggregatePublicKey.aggregate(s.signing_keys)
             if agg.point is None:

@@ -58,8 +58,9 @@ MANIFEST_SCHEMA = 1
 MANIFEST_DOMAIN = "lighthouse-tpu/aot-manifest/v1:"
 
 # The registered program set eligible for AOT capture from the serving
-# path: the batch-verify kernels (both h2c modes) and the rare-path
-# aggregate kernel.  Keep this a literal tuple — the ``aot-manifest``
+# path: the batch-verify kernels (both h2c modes), the rare-path
+# aggregate kernel and the public-key check that runs on every flush
+# carrying fresh keys.  Keep this a literal tuple — the ``aot-manifest``
 # registry-lint family AST-parses it and cross-references (a) every name
 # here against the kernel definitions in backend.py and (b) every
 # manifest entry's ``kernel`` field against this set (orphans are
@@ -68,6 +69,7 @@ AOT_KERNELS = (
     "_verify_kernel",
     "_verify_kernel_h2c",
     "_aggregate_verify_kernel",
+    "_g1_keycheck_kernel",
 )
 
 

@@ -377,6 +377,39 @@ def _verify_kernel_h2c(pk_aff, sig_aff, u0, u1, wbits):
     return _verify_kernel(pk_aff, sig_aff, h_aff, wbits)
 
 
+def _g1_keycheck_kernel(pk_aff):
+    """Public-key validation on the device: one bool per lane, whether
+    the lane's affine G1 key lies in the prime-order subgroup (Scott's
+    test, points.g1_subgroup_check).  The keys' on-curve check and the
+    infinity rejection stay with decompression on the host."""
+    return P.g1_subgroup_check(pk_aff)
+
+
+class KeycheckError(RuntimeError):
+    """A key-check call whose known-answer lanes answered wrong: none of
+    its verdicts can be trusted."""
+
+
+def _first_g1_non_member():
+    """The on-curve point with the smallest x > 0 outside G1 (a full-
+    order non-member, unlike the order-3 points at x = 0)."""
+    from ..curve import B1, g1_subgroup_check
+
+    x = 1
+    while True:
+        y = (Fp(x).square() * Fp(x) + B1).sqrt()
+        if y is not None and not g1_subgroup_check((Fp(x), y)):
+            return (Fp(x), y)
+        x += 1
+
+
+#: the two known-answer lanes of every key-check call: a G1 member and
+#: an on-curve point outside G1, so a device that answers either
+#: polarity wrong is caught inside the call it lies in
+KEYCHECK_CANARIES = (G1_GENERATOR, _first_g1_non_member())
+KEYCHECK_CANARY_ANSWERS = (True, False)
+
+
 def _pack_wbits(weights: list[int]) -> np.ndarray:
     """(64, B) MSB-first weight bits, vectorized (was a 64xB Python loop).
     Ingested as two uint32 halves: numpy rejects Python ints >= 2^63 when
@@ -568,6 +601,65 @@ class JaxBackend:
         )
         return MarshalledBatch(B, B, self.device_h2c, args)
 
+    # -- public-key validation (the G1 subgroup check) ---------------------
+
+    @property
+    def keycheck_size(self) -> int:
+        """The lanes of one key-check call: the smallest padded batch
+        size.  A flush's fresh keys fit one call; more keys take several
+        calls of that size."""
+        return self.batch_sizes[0]
+
+    def _keycheck_kernel(self, B: int):
+        key = ("g1check", B)
+        if key not in self._kernels:
+            fp_hex = program_fingerprint("_g1_keycheck_kernel", B=B)
+            if not self._install_from_store(key, fp_hex):
+                self._kernels[key] = traced_jit(
+                    _g1_keycheck_kernel, fp_hex,
+                    capture=self._aot_capture(key, "_g1_keycheck_kernel"),
+                )
+        return self._kernels[key]
+
+    def g1_keycheck(self, points) -> tuple:
+        """``(in_g1, lanes)``: whether each affine G1 point (on the curve,
+        not infinity) lies in G1, answered by the device, and the lanes
+        the calls used.  Every call of ``keycheck_size`` lanes carries the
+        two ``KEYCHECK_CANARIES`` lanes after its keys; all calls are
+        dispatched before any is read.  Raises :class:`KeycheckError`
+        when a known-answer lane answers wrong."""
+        B = self.keycheck_size
+        per_call = B - len(KEYCHECK_CANARIES)
+        chunks = [list(points[i:i + per_call])
+                  for i in range(0, len(points), per_call)]
+        calls = [(len(c), self._keycheck_dispatch(c, B)) for c in chunks]
+        out = []
+        for n, handle in calls:
+            out += self._keycheck_read(handle, n)
+        return out, B * len(calls)
+
+    def _keycheck_dispatch(self, keys: list, B: int):
+        lanes = keys + list(KEYCHECK_CANARIES)
+        lanes += [G1_GENERATOR] * (B - len(lanes))
+        return self._keycheck_kernel(B)(P.g1_encode(lanes))
+
+    @staticmethod
+    def _keycheck_read(handle, n: int) -> list:
+        got = [bool(v) for v in np.asarray(handle)]
+        known = tuple(got[n:n + len(KEYCHECK_CANARIES)])
+        if known != KEYCHECK_CANARY_ANSWERS:
+            raise KeycheckError(f"key-check known-answer lanes read {known}")
+        return got[:n]
+
+    def warm_keycheck(self, B: int) -> None:
+        """Compile the key check ahead of traffic (``B`` must be
+        ``keycheck_size``): one call with only its known-answer lanes,
+        which are checked."""
+        if B != self.keycheck_size:
+            raise ValueError(f"{B} is not the key-check size "
+                             f"{self.keycheck_size}")
+        self._keycheck_read(self._keycheck_dispatch([], B), 0)
+
     # -- single/aggregate verification reuses the set machinery ------------
 
     def verify(self, pubkey, msg: bytes, sig) -> bool:
@@ -657,7 +749,9 @@ class JaxBackend:
         for idx, s in enumerate(sets):
             if s.signature.point is None:
                 return MarshalledBatch(n, 0, self.device_h2c, invalid=True)
-            if not s.signing_keys:
+            if not s.signing_keys or not s.keys_valid():
+                # the kernel takes every key as a G1 member: one that is
+                # not (or was decoded unchecked and fails now) fails here
                 return MarshalledBatch(n, 0, self.device_h2c, invalid=True)
             if len(s.signing_keys) == 1:
                 # the dominant gossip case: nothing to aggregate

@@ -3,8 +3,8 @@
 Device analog of blst's point ops as used by verify_signature_sets
 (reference: crypto/bls/src/impls/blst.rs:71-117): doubling, addition,
 batched 64-bit scalar multiplication (the random batch weights, RAND_BITS=64
-at blst.rs:14), the psi endomorphism and Scott's fast G2 subgroup test
-(constants from endo.py, derived + self-checked there).
+at blst.rs:14), the psi and phi endomorphisms and Scott's fast G2 and G1
+subgroup tests (constants from endo.py, derived + self-checked there).
 
 A point is a pytree ``(X, Y, Z, inf)``: Jacobian coordinates (x = X/Z^2,
 y = Y/Z^3) in the lazy LFp representation plus an explicit boolean infinity
@@ -326,6 +326,39 @@ def g2_subgroup_check(xy):
     xQ = scalar_mul_bits(FP2_OPS, Q, bits)  # [|x|]Q
     psiQ = from_affine(FP2_OPS, psi_affine(xy))
     return jac_eq(FP2_OPS, psiQ, pt_neg(FP2_OPS, xQ))
+
+
+# ---------------------------------------------------------------------------
+# G1 endomorphism + fast subgroup check (the public-key validation)
+# ---------------------------------------------------------------------------
+
+_X_ABS = abs(params.X)
+_BETA_LIMBS = F.int_to_limbs(_endo.BETA * F.R_INT % F.P_INT)
+
+
+def phi_affine(xy):
+    """phi(x, y) = (beta x, y) on an affine G1 point pytree (x, y)."""
+    x, y = xy
+    beta = F.LFp(F.bcast(jnp.asarray(_BETA_LIMBS), F.batch_shape(x)), 1.0)
+    return (F.mont_mul(x, beta), y)
+
+
+def g1_subgroup_check(xy):
+    """Scott's test:  P in G1  iff  phi(P) == [x^2 - 1]P, the multiple
+    taken as [|x|+1]([|x|-1]P) (two 64-bit ladders; |x|^2 - 1 = x^2 - 1).
+    Batched over trailing dims; returns bools.  Inputs must be valid
+    curve points (decompression enforces on-curve).
+
+    The ladders use the fast add, exact for a member.  On a non-member
+    of small order an add can meet +-acc; that leaves Z = 0 (H = 0 in
+    the add), and Z stays 0 through every later double and add, so the
+    test also demands a finite result with Z != 0: without it, points of
+    order 3 and 11 pass."""
+    Pt = from_affine(FP_OPS, xy)
+    t = scalar_mul_const(FP_OPS, scalar_mul_const(FP_OPS, Pt, _X_ABS - 1),
+                         _X_ABS + 1)
+    finite = jnp.logical_not(t[3] | F.fp_is_zero(t[2]))
+    return finite & jac_eq(FP_OPS, from_affine(FP_OPS, phi_affine(xy)), t)
 
 
 # ---------------------------------------------------------------------------

@@ -174,7 +174,8 @@ class IngestEngine:
             # malformed set invalidates the whole batch (the resilient
             # ladder re-verifies set-by-set to isolate it).
             for s in sets:
-                if s.signature.point is None or not s.signing_keys:
+                if (s.signature.point is None or not s.signing_keys
+                        or not s.keys_valid()):
                     return MarshalledBatch(n, 0, backend.device_h2c,
                                            invalid=True)
 

@@ -74,6 +74,8 @@ SPANS: dict[str, str] = {
     # multi-tenant verification front door (serve/service.py)
     "serve.submit": "one tenant submission: admission through enqueue",
     "serve.dispatch": "one coalesced device batch: flush through verdicts",
+    "serve.decode": "public-key decode of one POST at the HTTP edge",
+    "serve.keycheck": "device G1 check of one flush's fresh public keys",
     # verdict-integrity layer (integrity/guard.py, integrity/selfcheck.py)
     "integrity.canary": "canary known-answer sweep around one dispatch",
     "integrity.audit": "cross-arm audit re-verify of a sampled batch",

@@ -12,8 +12,11 @@ the reference's ``http_api``):
                  "message": "0x..."}, ...]}
 
   202 with ``{"data": {"request_id": "r00000001", "status": "queued"}}``
-  on admission; 400 malformed, 429 rate-limit / queue-full, 503
-  degraded-mode shed.
+  on admission; 400 malformed (bad JSON or hex, or a key or signature
+  that names no curve point, or a key at infinity), 429 rate-limit /
+  queue-full, 503 degraded-mode shed.  A key on the curve but outside
+  G1 is admitted: the flush's device key check rejects it, and its set
+  polls back ``false`` while its neighbours are verified.
 * ``GET /eth/v1/verify/batch/<request_id>`` — poll verdicts: ``queued``
   or ``done`` with per-set booleans and the deadline-miss flag; 404 for
   ids never admitted (or evicted after completion).
@@ -33,6 +36,8 @@ import threading
 from collections import OrderedDict
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
+from ..obs.tracer import TRACER
+from ..utils import metrics as M
 from ..utils.logging import get_logger
 
 log = get_logger("serve.http")
@@ -62,58 +67,105 @@ def _unhex(s: str) -> bytes:
 
 
 class PubkeyDecodeCache:
-    """Bounded LRU of decoded, subgroup-checked public keys keyed by
-    their wire bytes.  Decoding one costs ~9 ms of Python, almost all of
-    it the subgroup check, and tenants send the same validator keys in
-    batch after batch: a 440-member committee set would otherwise spend
-    ~4 s in decode alone.  Bytes that fail to decode are never cached."""
+    """Bounded LRU of decompressed public keys keyed by their wire bytes,
+    and a smaller one of the keys found outside G1.
+
+    A miss decompresses only (the on-curve ``sqrt``, ~0.4 ms of Python):
+    bytes that name no curve point, or infinity, still raise, and are
+    never cached.  The subgroup check, ~9 ms per key in Python, is left
+    to the flush that verifies the key (``VerifyService._validate_keys``
+    checks a flush's fresh keys in one device call), and its verdict
+    stays on the cached key object, so a repeated key is checked once.
+    A key the check rejected moves to the rejected LRU on its next
+    lookup and keeps coming back rejected, so a tenant repeating a bad
+    key costs no check and cannot evict the good ones."""
+
+    #: rejected keys kept; a tenant cycling more bad keys than this pays
+    #: a decompression and a device lane per key again, no more
+    REJECTED_CAPACITY = 1 << 12
 
     def __init__(self, capacity: int = 1 << 16):
         self.capacity = int(capacity)
         self._lock = threading.Lock()
         self._keys: OrderedDict = OrderedDict()
+        self._rejected: OrderedDict = OrderedDict()
 
     def decode(self, data: bytes):
+        return self.lookup(data)[0]
+
+    def lookup(self, data: bytes) -> tuple:
+        """``(key, missed)``: the cached key for ``data``, or a key just
+        decompressed from it (``missed``)."""
         from ..crypto.bls.api import PublicKey
 
         with self._lock:
+            pk = self._rejected.get(data)
+            if pk is not None:
+                self._rejected.move_to_end(data)
+                return pk, False
             pk = self._keys.get(data)
             if pk is not None:
-                self._keys.move_to_end(data)
-                return pk
-        pk = PublicKey.from_bytes(data)
+                if pk.subgroup_checked and not pk.subgroup_check():
+                    del self._keys[data]
+                    self._put(self._rejected, data, pk,
+                              self.REJECTED_CAPACITY)
+                else:
+                    self._keys.move_to_end(data)
+                return pk, False
+        pk = PublicKey.from_bytes_unchecked(data)
         with self._lock:
-            self._keys[data] = pk
-            while len(self._keys) > self.capacity:
-                self._keys.popitem(last=False)
-        return pk
+            self._put(self._keys, data, pk, self.capacity)
+        return pk, True
+
+    @staticmethod
+    def _put(lru: OrderedDict, data: bytes, pk, capacity: int) -> None:
+        lru[data] = pk
+        while len(lru) > capacity:
+            lru.popitem(last=False)
 
 
 def decode_sets(raw, pubkeys: PubkeyDecodeCache | None = None) -> list:
-    """Wire set dicts -> validated ``SignatureSet`` objects.  Raises
-    ``ValueError`` on any shape or point-decode problem (the transport
-    maps that to 400)."""
+    """Wire set dicts -> ``SignatureSet`` objects.  Raises ``ValueError``
+    on any shape or point-decode problem (the transport maps that to
+    400).  With ``pubkeys`` the keys come from that cache, decompressed
+    but not yet subgroup-checked (the set's ``keys_unvalidated``); the
+    key decode is one ``serve.decode`` span."""
     from ..crypto.bls.api import PublicKey, Signature, SignatureSet
-
-    decode_pk = PublicKey.from_bytes if pubkeys is None else pubkeys.decode
 
     if not isinstance(raw, list) or not raw:
         raise ValueError("sets must be a non-empty list")
-    out = []
+    parsed = []
     for i, entry in enumerate(raw):
         if not isinstance(entry, dict):
             raise ValueError(f"set {i}: expected an object")
         try:
             sig = Signature.from_bytes(_unhex(entry["signature"]))
-            pks = [decode_pk(_unhex(p)) for p in entry["pubkeys"]]
+            keys = [_unhex(p) for p in entry["pubkeys"]]
             msg = _unhex(entry["message"])
         except (KeyError, TypeError, ValueError) as exc:
             raise ValueError(f"set {i}: {exc}") from exc
         except Exception as exc:  # point decode (BlsError subclasses vary)
             raise ValueError(f"set {i}: {exc}") from exc
-        if not pks:
+        if not keys:
             raise ValueError(f"set {i}: empty pubkeys")
-        out.append(SignatureSet(sig, pks, msg))
+        parsed.append((sig, keys, msg))
+    out = []
+    misses = 0
+    n_keys = sum(len(keys) for _sig, keys, _msg in parsed)
+    with TRACER.span("serve.decode", keys=n_keys) as sp:
+        for i, (sig, keys, msg) in enumerate(parsed):
+            try:
+                if pubkeys is None:
+                    pks = [PublicKey.from_bytes(k) for k in keys]
+                else:
+                    looked = [pubkeys.lookup(k) for k in keys]
+                    misses += sum(missed for _pk, missed in looked)
+                    pks = [pk for pk, _missed in looked]
+            except Exception as exc:  # point decode (BlsError subclasses vary)
+                raise ValueError(f"set {i}: {exc}") from exc
+            out.append(SignatureSet(sig, pks, msg))
+        sp.add(misses=misses)
+    M.SERVE_DECODE_MISSES.inc(misses)
     return out
 
 

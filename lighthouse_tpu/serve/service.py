@@ -10,13 +10,18 @@ One submission travels:
   submit (admission, ``serve.submit`` chaos + span)
     -> DeadlineAwareBatcher (fill-or-flush pooling)
       -> tick (``serve.dispatch`` chaos + span, one device batch)
-        -> verify_batch -> per-request verdict slices -> poll
+        -> key validation (``serve.keycheck``: the flush's unchecked
+           public keys in one device call)
+          -> verify_batch -> per-request verdict slices -> poll
 
 Verdict fidelity: a dispatch concatenates the admitted requests' sets in
 FIFO order and hands them to ``verify_batch`` in ONE call, so the
 verdicts a tenant polls back are byte-identical to calling the wrapped
 verifier directly on the same stream — the acceptance invariant the
-serve tests pin.
+serve tests pin.  The one exception is key validation, which the edge
+leaves to the flush: a set holding a key outside G1 gets ``false``
+without entering the batch, the verdict a verifier that validates its
+keys (the CPU rung, the reference) gives it.
 
 ``tick`` is the service's never-raise pump (registered in the analysis
 never-raise registry): a dispatch failure fails the affected requests
@@ -299,8 +304,12 @@ class VerifyService:
                          requests=len(reqs), n_sets=len(sets)):
             try:
                 self._injector.fire("serve.dispatch")
-                outcome = self._verifier.verify_batch(sets)
-                verdicts = list(outcome.verdicts)
+                rejected = self._validate_keys(sets)
+                keep = [s for i, s in enumerate(sets) if i not in rejected]
+                out = iter(self._verifier.verify_batch(keep).verdicts
+                           if keep else ())
+                verdicts = [False if i in rejected else next(out)
+                            for i in range(len(sets))]
             except Exception:
                 # infrastructure failure past the resilient ladder (or an
                 # injected one): fail the whole batch closed, keep serving
@@ -332,6 +341,52 @@ class VerifyService:
                 self._requests.pop(self._done_order.pop(0), None)
         for r in reqs:
             self.admission.release(r.tenant, len(r.sets))
+
+    def _validate_keys(self, sets) -> set:
+        """The indices of the sets holding a key outside G1.
+
+        Only sets built from keys not known to be in G1 are looked at (a
+        ``SignatureSet``'s ``keys_unvalidated``: one flag per set).  Their
+        unchecked keys, each once, are checked in one device call
+        (``serve.keycheck``), and each verdict stays on the key object,
+        so the edge's decode cache never asks twice.  Without a device
+        backend, or when the call fails or a known-answer lane answers
+        wrong, the keys are checked on the host; a failed device call
+        counts as a degraded batch."""
+        flagged = [i for i, s in enumerate(sets)
+                   if getattr(s, "keys_unvalidated", False)]
+        if not flagged:
+            return set()
+        pending = {}
+        for i in flagged:
+            for pk in sets[i].signing_keys:
+                if not pk.subgroup_checked:
+                    pending[id(pk)] = pk
+        if pending:
+            keys = list(pending.values())
+            with TRACER.span("serve.keycheck", keys=len(keys)) as sp:
+                in_g1, lanes = self._check_keys(keys)
+                sp.add(lanes=lanes, rejected=in_g1.count(False))
+            for pk, ok in zip(keys, in_g1):
+                pk.record_subgroup_check(ok)
+            M.SERVE_KEYS_REJECTED.inc(in_g1.count(False))
+        return {i for i in flagged if not sets[i].keys_valid()}
+
+    def _check_keys(self, keys) -> tuple:
+        """``(in_g1, device lanes)`` for the keys' points."""
+        backend = getattr(self.stack, "backend", None)
+        if backend is not None:
+            try:
+                in_g1, lanes = backend.g1_keycheck([pk.point for pk in keys])
+                M.SERVE_KEYS_CHECKED_DEVICE.inc(len(keys))
+                return in_g1, lanes
+            except Exception:
+                log.error("device key check failed; keys checked on the "
+                          "host", exc_info=True)
+                M.VERIFY_DEGRADED_BATCHES.inc()
+        from ..crypto.bls.curve import g1_subgroup_check
+
+        return [g1_subgroup_check(pk.point) for pk in keys], 0
 
     # -- background pump ---------------------------------------------------
 

@@ -74,10 +74,11 @@ class VerifyStack:
         """Compile every program this stack dispatches at each padded
         batch size in ``sizes`` before traffic arrives: the backend's
         single-device kernel (the ladder's rung) and, behind a pod, the
-        sharded programs.  The programs compile concurrently — tracing
-        holds the interpreter lock, the compiler does not — and the
-        result maps each size to the seconds until its last program was
-        ready.  A no-op ({}) without a device backend."""
+        sharded programs; and the public-key check at the backend's
+        ``keycheck_size``.  The programs compile concurrently
+        — tracing holds the interpreter lock, the compiler does not —
+        and the result maps each size to the seconds until its last
+        program was ready.  A no-op ({}) without a device backend."""
         if self.backend is None:
             return {}
         # the registry decides whether the pod's deferred-pubkey program
@@ -91,6 +92,7 @@ class VerifyStack:
             if self.pod is not None:
                 jobs += [(int(B), self.pod.warm_compile),
                          (int(B), self.pod.warm_registry)]
+        jobs.append((self.backend.keycheck_size, self.backend.warm_keycheck))
         secs: dict = {}
         errors: list = []
         lock = threading.Lock()

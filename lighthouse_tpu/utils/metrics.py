@@ -508,6 +508,21 @@ SERVE_ERRORS = Counter(
     "VerifyService dispatch failures absorbed by the never-raise tick "
     "(affected requests fail closed)",
 )
+SERVE_DECODE_MISSES = Counter(
+    "serve_decode_misses_total",
+    "Public keys the HTTP edge decompressed because its decode cache "
+    "did not hold them",
+)
+SERVE_KEYS_CHECKED_DEVICE = Counter(
+    "serve_keys_checked_device_total",
+    "Public keys whose G1 subgroup check the device answered, in the "
+    "batched key check of a flush",
+)
+SERVE_KEYS_REJECTED = Counter(
+    "serve_keys_rejected_total",
+    "Public keys found outside G1 by a flush's key check; their sets "
+    "get verdict false without being verified",
+)
 SERVE_QUEUE_WAIT = Histogram(
     "serve_queue_wait_seconds",
     "Wait between admission and batch dispatch, by tenant — the price of "

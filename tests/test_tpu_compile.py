@@ -118,3 +118,19 @@ def test_verify_kernel_compiles_for_v5e(one_chip, monkeypatch):
         mb.args)
     assert "tpu_custom_call" in _compiled_text(BK._verify_kernel_h2c,
                                                *shapes)
+
+
+def test_g1_keycheck_kernel_compiles_for_v5e(one_chip, monkeypatch):
+    """The public-key check at 64 lanes, staged as a TPU process stages
+    it (see the verify kernel's test): its products are Mosaic kernels."""
+    from lighthouse_tpu.crypto.bls.curve import G1_GENERATOR
+    from lighthouse_tpu.crypto.bls.jax_backend import backend as BK
+    from lighthouse_tpu.crypto.bls.jax_backend import points as P
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(F, "_PALLAS_MODE", None)
+    shapes = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        P.g1_encode([G1_GENERATOR] * 64))
+    assert "tpu_custom_call" in _compiled_text(BK._g1_keycheck_kernel,
+                                               shapes)
